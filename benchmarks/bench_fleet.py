@@ -43,13 +43,6 @@ from repro.utils.hostdev import force_host_device_count
 # has a multi-device point, even on a 1-core container — virtual devices
 # oversubscribe threads; structure stays, speedup needs real cores)
 force_host_device_count(max(os.cpu_count() or 1, 2))
-# the legacy XLA:CPU runtime dispatches the write-step's many tiny
-# gather/scatter ops ~2.5× faster than the thunk runtime on this workload
-# (measured: 40k → 99k fleet steps/s on the default grid); numerics are
-# unchanged — it is the same compiled computation under a different
-# executor. Override by putting the flag in XLA_FLAGS yourself.
-if "--xla_cpu_use_thunk_runtime" not in os.environ["XLA_FLAGS"]:
-    os.environ["XLA_FLAGS"] += " --xla_cpu_use_thunk_runtime=false"
 
 import json
 import pathlib
@@ -60,6 +53,7 @@ from repro.core import managers as M
 from repro.core import workloads as W
 from repro.core.fleet import DriveSpec, simulate_fleet
 from repro.core.ssd import Geometry
+from repro.utils import compile_cache
 
 from benchmarks.common import report, table, timer
 
@@ -121,12 +115,7 @@ def grid_specs(geom: Geometry, writes: int, seeds=(0,),
 def run(full: bool = False, smoke: bool = False,
         out_path: str | None = None, only: str | None = None,
         devices: int | None = None) -> dict:
-    # compile-once within this run comes from the in-process runner memo
-    # (fleet_exec); the on-disk compilation cache is NOT enabled here —
-    # set REPRO_JAX_CACHE_DIR to opt in (simulate_fleet wires it), but
-    # see the hazard note on enable_persistent_compilation_cache first:
-    # on jaxlib 0.4.37/XLA:CPU, serializing the Pallas-bearing step
-    # executables corrupts the heap and kills the bench mid-grid
+    compile_cache.enable()
     geom = Geometry(n_luns=4, blocks_per_lun=32, pages_per_block=8)
     writes = 60_000 if full else (4_000 if smoke else 20_000)
     seeds = (0,) if smoke else (0, 1)  # 5 policies × 5 workloads × seeds
@@ -191,7 +180,8 @@ def run(full: bool = False, smoke: bool = False,
     scaling = {}
     if devices is None and only is None:
         n_host = len(jax.devices())
-        for d in sorted({1, 2, n_host}):
+        # only device counts that exist: a count above n_host is an error
+        for d in sorted({1, min(2, n_host), n_host}):
             kw = dict(fleet_kw, devices=d)
             simulate_fleet(geom, specs, **kw)  # warm (compile excluded)
             d_sec = None

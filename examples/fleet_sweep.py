@@ -32,9 +32,11 @@ from repro.core import managers as M
 from repro.core import workloads as W
 from repro.core.fleet import DriveSpec, simulate_fleet
 from repro.core.ssd import Geometry
+from repro.utils import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--writes", type=int, default=20_000)
     ap.add_argument("--seeds", type=int, default=1)

@@ -16,6 +16,9 @@ from repro.core import (
 from repro.core import managers as M
 from repro.core import workloads as W
 from repro.core.ssd import Geometry
+from repro.utils import compile_cache
+
+compile_cache.enable()
 
 print("=== 1. The closed-form WA model (paper §4) ===")
 for r in (0.6, 0.7, 0.8, 0.9):

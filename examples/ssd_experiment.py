@@ -12,9 +12,11 @@ import numpy as np
 from repro.core import managers as M
 from repro.core import workloads as W
 from repro.core.ssd import Geometry
+from repro.utils import compile_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", choices=("uniform", "swap", "tpcc", "exp5"),
                     default="swap")

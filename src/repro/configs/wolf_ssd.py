@@ -31,6 +31,15 @@ class SSDConfig:
     def lba_pages(self) -> int:
         return int(self.pba_pages * self.lba_pba)
 
+    def geometry(self):
+        """This drive as the simulator's :class:`repro.core.ssd.Geometry`."""
+        from repro.core.ssd import Geometry
+
+        return Geometry(
+            n_luns=self.n_luns, blocks_per_lun=self.blocks_per_lun,
+            pages_per_block=self.pages_per_block, lba_pba=self.lba_pba,
+        )
+
     def scaled(self, block_factor: int = 16, page_factor: int = 4) -> "SSDConfig":
         return dataclasses.replace(
             self,

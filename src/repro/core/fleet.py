@@ -41,9 +41,9 @@ Execution architecture (core/fleet_exec.py owns the device side):
   execution instead of serializing with it, which is where the old
   executor spent its host time on large grids.
 * **Compile amortization** — per-sub-batch runners are memoized on
-  (step structure × geometry × scan length × device count), with optional
-  on-disk persistence (``fleet_exec.enable_persistent_compilation_cache``),
-  so sweeps that revisit a structure compile once. On CPU, spawn virtual
+  (step structure × geometry × scan length × device count), so sweeps
+  that revisit a structure compile once (entry points add the on-disk
+  cache: ``repro.utils.compile_cache.enable``). On CPU, spawn virtual
   devices via :func:`repro.utils.hostdev.force_host_device_count` *before*
   the first jax import — the device count is locked at backend init (that
   is also why ``devices="auto"`` from a jax-already-imported entry point
@@ -77,7 +77,6 @@ Frankie-style effective-OP analytics.
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +85,6 @@ import numpy as np
 from repro.core.fleet_exec import (
     SubbatchFailure,
     SubbatchResolutionError,
-    enable_persistent_compilation_cache,
     pad_batch,
     resolve_devices,
     subbatch_runner,
@@ -137,9 +135,8 @@ class FleetResult:
     trace_every: int = 1  # trace stride (RunResult.stride of every drive)
     # per-sub-batch executor report, aligned with .shards: drive count,
     # devices actually used, and filler-drive padding. With mesh padding a
-    # ragged sub-batch always uses every requested device (devices ==
-    # min(requested, len(jax.devices()))) — this is the visible record of
-    # the effective shard count the old divisor clamp used to hide.
+    # ragged sub-batch uses min(requested devices, its drive count)
+    # devices — the visible record of the effective shard count.
     exec_meta: list[dict] = dataclasses.field(default_factory=list)
 
     @property
@@ -408,12 +405,13 @@ def simulate_fleet(
     phases, the bit-compatibility anchor of tests/test_write_engine.py.
 
     devices: None/1 = pure single-device vmap; "auto" = shard over all
-    jax.devices(); int = shard over that many (clamped to the visible
-    device count). Every sub-batch — ragged or not — uses the full
-    resolved device count: ragged sub-batches are padded with inert
-    filler drives (free: the pad fills otherwise-idle lanes) and the
-    filler rows never surface in results. ``FleetResult.exec_meta``
-    records drives/devices/padding per sub-batch. Results are
+    jax.devices(); int = shard over exactly that many (more than are
+    visible raises). Every sub-batch — ragged or not — uses the resolved
+    device count, or its drive count if smaller: ragged sub-batches are
+    padded with inert filler drives (free: the pad fills otherwise-idle
+    lanes) and the filler rows never surface in results.
+    ``FleetResult.exec_meta`` records drives/devices/padding per
+    sub-batch. Results are
     bit-identical across device counts. NOTE: on CPU the visible device
     count is locked at jax backend init — see
     ``repro.utils.hostdev.force_host_device_count``.
@@ -452,11 +450,6 @@ def simulate_fleet(
                 f"fleet drives must share ManagerConfig.{f} "
                 "(a static paper constant)"
             )
-    # on-disk compile cache: strictly opt-in via env — see the hazard
-    # note on enable_persistent_compilation_cache (jaxlib 0.4.37/XLA:CPU
-    # heap corruption when serializing the Pallas-bearing executables)
-    if os.environ.get("REPRO_JAX_CACHE_DIR"):
-        enable_persistent_compilation_cache()
     n_dev = resolve_devices(devices)
     p_max = max(len(s.phases) for s in specs)
     g_wl = max(len(ph.sizes) for s in specs for ph in s.phases)

@@ -6,7 +6,9 @@ arrays and the devices:
 * **Sharding** — each sub-batch runs as ``jit(shard_map(vmap(run_one)))``
   over :func:`repro.launch.mesh.drive_mesh`'s ``"drives"`` axis. Drives are
   independent lanes, so each device executes the plain vmapped scan over
-  its slice of the batch; numerics are bit-identical to the single-device
+  its slice of the batch (a device holding one drive scans it without the
+  vmap, so the step's conditionals stay real branches instead of selects
+  over both sides); numerics are bit-identical to the single-device
   ``jit(vmap(...))`` path (which remains the ``n_dev == 1`` special case —
   no mesh, no collective, same trace). The legacy ``pmap(vmap(...))``
   executor is gone: shard_map composes with jit (one dispatch, donation,
@@ -36,22 +38,17 @@ arrays and the devices:
   e.g. the same policy grid at a new seed set, or bench scaling curves —
   reuse the jitted runner and pay XLA compilation once per structure.
   :func:`step_cache_stats` exposes hit/miss counters (asserted by
-  tests/test_fleet_mesh.py); :func:`enable_persistent_compilation_cache`
-  additionally wires jax's on-disk compilation cache so the once-per-
-  structure cost survives process restarts. The on-disk layer is STRICTLY
-  opt-in (set ``REPRO_JAX_CACHE_DIR`` or call it explicitly) and carries a
-  hazard note — see the function docstring.
+  tests/test_fleet_mesh.py). Entry points that want the compiles to
+  survive the process call :func:`repro.utils.compile_cache.enable`.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 import warnings
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.core.simulator import SimContext, make_step, scan_writes
@@ -103,7 +100,8 @@ def resolve_devices(devices: int | str | None) -> int:
     """Resolve ``simulate_fleet``'s ``devices=`` argument to a device count.
 
     None/1 = single device; ``"auto"`` = every visible jax device; an int
-    (or numeric string) is clamped to the visible device count. On CPU the
+    (or numeric string) asks for exactly that many, and more than are
+    visible is an error, never a quiet smaller mesh. On CPU the
     visible count is an IMPORT-ORDER property — jax locks it at first
     backend init, so ``"auto"`` from an entry point that imported jax
     before setting ``--xla_force_host_platform_device_count`` sees 1
@@ -126,7 +124,13 @@ def resolve_devices(devices: int | str | None) -> int:
                 stacklevel=3,
             )
         return n_avail
-    return max(1, min(int(devices), n_avail))
+    n = int(devices)
+    if not 1 <= n <= n_avail:
+        raise ValueError(
+            f"devices={devices!r}: {n_avail} jax device(s) visible "
+            f"({jax.default_backend()})"
+        )
+    return n
 
 
 def pad_batch(tree, pad: int):
@@ -164,83 +168,6 @@ def step_cache_clear() -> None:
     _RUNNERS.clear()
     _STATS.hits = 0
     _STATS.misses = 0
-
-
-_PERSISTENT_WIRED = False
-
-# jaxlib builds whose XLA:CPU executable serialization corrupts the heap
-# when the Pallas-bearing step executables are written to the on-disk
-# cache (bisected on 0.4.37; 0.4.36 ships the same serialization path).
-# See the hazard note on enable_persistent_compilation_cache.
-_CACHE_BAD_JAXLIB_CPU = ("0.4.36", "0.4.37")
-
-
-def _persistent_cache_hazard() -> str | None:
-    """Return a reason string when the running jaxlib/backend combo is
-    known to corrupt the heap with the on-disk cache enabled, else None."""
-    try:
-        import jaxlib
-        jaxlib_version = jaxlib.__version__
-    except Exception:  # pragma: no cover — jaxlib always ships with jax
-        jaxlib_version = jax.__version__
-    if (
-        jax.default_backend() == "cpu"
-        and jaxlib_version in _CACHE_BAD_JAXLIB_CPU
-    ):
-        return (
-            f"jaxlib {jaxlib_version} on XLA:CPU corrupts the process heap "
-            "when serializing Pallas-bearing step executables "
-            "(malloc_consolidate/segfault after ~a dozen cached compiles)"
-        )
-    return None
-
-
-def enable_persistent_compilation_cache(path: str | None = None) -> str:
-    """Wire jax's on-disk compilation cache (idempotent).
-
-    The in-process memo deduplicates compiles within a run; this extends
-    the once-per-step-structure guarantee across processes — a sweep
-    driver that restarts per grid, or repeated bench runs, reload the XLA
-    executable instead of recompiling. Default location
-    ``$REPRO_JAX_CACHE_DIR`` or ``~/.cache/repro_jax_cache``.
-
-    .. warning::
-        Opt-in for a reason: on jaxlib 0.4.37's XLA:CPU backend,
-        serializing the Pallas-kernel-bearing step executables corrupts
-        the process heap — after roughly a dozen cached compiles the
-        process dies with ``malloc_consolidate()`` / segfault. Bisected:
-        a plain-jax scan caches fine under the same config; any mix of
-        this module's runners and the per-drive step jits crashes once
-        enough executables are written, with or without donation and on
-        both CPU runtimes (thunk and legacy). Nothing in the repo enables
-        this by default, and since the fault-robustness pass this note is
-        ENFORCED: on a known-bad jaxlib/backend combo
-        (:data:`_CACHE_BAD_JAXLIB_CPU` × XLA:CPU) the call warns and
-        refuses to wire the cache instead of arming a delayed crash. Set
-        ``REPRO_JAX_CACHE_FORCE=1`` to override on a build you have
-        re-validated (a full bench run survives with the cache on).
-    """
-    global _PERSISTENT_WIRED
-    path = path or os.environ.get(
-        "REPRO_JAX_CACHE_DIR", os.path.expanduser("~/.cache/repro_jax_cache")
-    )
-    if _PERSISTENT_WIRED:
-        return path
-    hazard = _persistent_cache_hazard()
-    if hazard and not os.environ.get("REPRO_JAX_CACHE_FORCE"):
-        warnings.warn(
-            f"refusing to enable the on-disk compilation cache: {hazard}. "
-            "Set REPRO_JAX_CACHE_FORCE=1 to override on a re-validated "
-            "build.",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return path
-    jax.config.update("jax_compilation_cache_dir", path)
-    # sim steps compile in O(seconds); cache anything non-trivial
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    _PERSISTENT_WIRED = True
-    return path
 
 
 def subbatch_runner(ctx: SimContext, n_total: int, on_device_sampler: bool,
@@ -288,16 +215,24 @@ def subbatch_runner(ctx: SimContext, n_total: int, on_device_sampler: bool,
         st, trace = scan_writes(ctx, step, st, lbas, ts, ops)
         return st, trace, lbas
 
-    batched = jax.vmap(run_one)
+    def batched(*args):
+        # one lane per device: scan it unbatched. Under vmap every
+        # lax.cond of the step is a select that runs both branches (the GC
+        # drains, the §5.6 demotion replay) on every write; unbatched they
+        # are real branches taken only when needed. Same values either way.
+        if jax.tree_util.tree_leaves(args)[0].shape[0] == 1:
+            out = run_one(*jax.tree_util.tree_map(lambda a: a[0], args))
+            return jax.tree_util.tree_map(lambda a: a[None], out)
+        return jax.vmap(run_one)(*args)
+
     if n_dev > 1:
         spec = PartitionSpec("drives")
-        # check_rep=False: the replication checker has no rule for
-        # lax.while_loop (the GC/valve drains) in this jax version; the
-        # body is collective-free with fully partitioned in/outs, so the
-        # check is vacuous here anyway.
-        batched = shard_map(
+        # check_vma=False: the body is collective-free with fully
+        # partitioned in/outs, so the varying-axes check has nothing to
+        # prove
+        batched = jax.shard_map(
             batched, mesh=drive_mesh(n_dev), in_specs=spec, out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )
     fn = jax.jit(batched, donate_argnums=(0,))
     _RUNNERS[key] = fn

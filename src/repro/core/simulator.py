@@ -1284,7 +1284,10 @@ def _interval_update(ctx: SimContext, st: SimState, policy):
     u = st.grp_writes.astype(jnp.float32) / policy["h"].astype(jnp.float32)
     active = st.grp_active
     st = st.replace(
-        grp_p=jnp.where(active, st.grp_p * (1.0 - a) + a * u, 0.0),
+        # p·(1−a) + a·u written with ONE product: LLVM contracts a
+        # mul+add into an FMA, and with two products it picked a different
+        # one batched and unbatched (a one-ulp split between vmap and jit)
+        grp_p=jnp.where(active, st.grp_p + a * (u - st.grp_p), 0.0),
         grp_writes=jnp.zeros_like(st.grp_writes),
         interval=st.interval + 1,
         cooldown=jnp.maximum(st.cooldown - 1, 0),

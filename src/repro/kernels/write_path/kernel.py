@@ -2,20 +2,22 @@
 
 The simulator's steady-state write (invalidate the page's old slot, append
 it to the target group's open block, repoint the packed map) is three
-single-element updates on three pools. Issued as separate XLA ops they are
-three kernel launches a step; the Pallas form makes the update list a
-scalar-prefetch operand — one [4] int32 row ``(lba, old_pm, new_pm, ok)``
-— and lands all three pools in one kernel with the pools aliased in place,
-mirroring ``kernels/gc_compact``.
+single-element updates on three pools. The kernel lands the two [K, B]
+slot-pool updates in one call: the update row ``(lba, old_pm, new_pm, ok)``
+is a scalar-prefetch operand, the pools stay in HBM aliased in place, and
+each touched slot is a read-modify-write of its block's row through SMEM
+(see ``kernels/hbm_rows``) — the invalidate first, then the append, so a
+shared row sees both. The packed ``page_map`` entry is one dynamic-update-
+slice outside the kernel: the map is a 1-D [LBA] pool whose length need
+not be a multiple of the 128-lane tile, so no aligned row DMA covers its
+tail.
 
-The pools arrive FLATTENED ([LBA] and [K·B]) and reshaped to (N, 1) tiles
-so the single-element stores are plain 2-D dynamic slices. ``ok`` masks the
-whole op (a disabled call must leave every pool untouched) and
-``old_pm < 0`` masks just the invalidate.
+``ok`` masks the whole op (a disabled call must leave every pool untouched)
+and ``old_pm < 0`` masks just the invalidate.
 
-``apply_trim`` is the discard peer: the same scalar-prefetch shape with the
-append dropped — clear the old slot's valid bit, store -1 into the packed
-map. It backs the op-stream engine's TRIM fast path on TPU.
+``apply_trim`` is the discard peer: the same shape with the append dropped
+— clear the old slot's valid bit, store -1 into the packed map. It backs
+the op-stream engine's TRIM fast path on TPU.
 """
 
 from __future__ import annotations
@@ -25,24 +27,97 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.hbm_rows import ANY, drive_batched, rmw_row
+
+_W = 4  # scalar-prefetch words per drive: (lba, old_pm, new_pm, ok)
 
 
-def _apply_write_kernel(ops_ref, pm_ref, lba_ref, val_ref,
-                        pm_out, lba_out, val_out):
-    lba = ops_ref[0, 0]
-    old = ops_ref[0, 1]
-    new = ops_ref[0, 2]
-    ok = ops_ref[0, 3] != 0
+def _clear_old(val_out, n, old, ok, buf, sem):
+    b = val_out.shape[2]
 
     @pl.when(ok & (old >= 0))
     def _clear():
-        val_out[pl.ds(old, 1), :] = jnp.zeros((1, 1), jnp.int32)
+        def update(row):
+            row[old % b] = jnp.int32(0)
+
+        rmw_row(val_out, n, old // b, buf, sem, update)
+
+
+def _apply_write_kernel(ops_ref, lba_in, val_in, lba_out, val_out, buf, sem):
+    del lba_in, val_in  # aliased to the outputs
+    n = pl.program_id(0)
+    b = val_out.shape[2]
+    lba = ops_ref[_W * n]
+    old = ops_ref[_W * n + 1]
+    new = ops_ref[_W * n + 2]
+    ok = ops_ref[_W * n + 3] != 0
+    _clear_old(val_out, n, old, ok, buf, sem)
 
     @pl.when(ok)
     def _set():
-        val_out[pl.ds(new, 1), :] = jnp.ones((1, 1), jnp.int32)
-        lba_out[pl.ds(new, 1), :] = jnp.full((1, 1), lba, jnp.int32)
-        pm_out[pl.ds(lba, 1), :] = jnp.full((1, 1), new, jnp.int32)
+        def set_valid(row):
+            row[new % b] = jnp.int32(1)
+
+        def set_lba(row):
+            row[new % b] = lba
+
+        rmw_row(val_out, n, new // b, buf, sem, set_valid)
+        rmw_row(lba_out, n, new // b, buf, sem, set_lba)
+
+
+def _apply_trim_kernel(ops_ref, val_in, val_out, buf, sem):
+    del val_in
+    n = pl.program_id(0)
+    old = ops_ref[_W * n + 1]
+    ok = ops_ref[_W * n + 3] != 0
+    _clear_old(val_out, n, old, ok, buf, sem)
+
+
+def _pool_call(kernel, pools, ops, interpret):
+    """Run ``kernel`` over [N, K, B] int32 pools with [N, _W] scalar ops."""
+    nb, _, b = pools[0].shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nb,),
+        in_specs=[ANY] * len(pools),
+        out_specs=[ANY] * len(pools),
+        scratch_shapes=[
+            pltpu.SMEM((b,), jnp.int32), pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(p.shape, jnp.int32) for p in pools],
+        input_output_aliases={i + 1: i for i in range(len(pools))},
+        interpret=interpret,
+    )(ops.reshape(-1), *pools)
+
+
+@functools.lru_cache(maxsize=None)
+def _write_op(interpret: bool):
+    return drive_batched(
+        lambda sl, va, ops: _pool_call(
+            _apply_write_kernel, (sl, va), ops, interpret
+        ),
+        n_out=2,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _trim_op(interpret: bool):
+    return drive_batched(
+        lambda va, ops: _pool_call(_apply_trim_kernel, (va,), ops, interpret),
+        n_out=1,
+    )
+
+
+def _ops_row(lba, old_pm, new_pm, enabled):
+    return jnp.stack(
+        [lba, old_pm, new_pm, jnp.asarray(enabled, jnp.int32)]
+    ).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -58,46 +133,16 @@ def apply_write(
     enabled: jax.Array | bool = True,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    kk, b = slot_lba.shape
-    new_pm = dst_blk * b + dst_slot
-    ops = jnp.stack(
-        [lba, old_pm, new_pm, jnp.asarray(enabled, jnp.int32)]
-    ).astype(jnp.int32)[None, :]
-    out = pl.pallas_call(
-        _apply_write_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((page_map.shape[0], 1), jnp.int32),
-            jax.ShapeDtypeStruct((kk * b, 1), jnp.int32),
-            jax.ShapeDtypeStruct((kk * b, 1), jnp.int32),
-        ),
-        input_output_aliases={1: 0, 2: 1, 3: 2},
-        interpret=interpret,
-    )(
-        ops,
-        page_map[:, None],
-        slot_lba.reshape(-1, 1),
-        valid.reshape(-1, 1).astype(jnp.int32),
+    b = slot_lba.shape[1]
+    new_pm = (dst_blk * b + dst_slot).astype(jnp.int32)
+    ops = _ops_row(lba, old_pm, new_pm, enabled)
+    lba_new, val_new = _write_op(interpret)(
+        slot_lba, valid.astype(jnp.int32), ops
     )
-    pm_new, lba_new, val_new = out
-    return (
-        pm_new[:, 0],
-        lba_new[:, 0].reshape(kk, b),
-        val_new[:, 0].astype(valid.dtype).reshape(kk, b),
+    page_map = page_map.at[lba].set(
+        jnp.where(ops[3] != 0, new_pm, page_map[lba])
     )
-
-
-def _apply_trim_kernel(ops_ref, pm_ref, val_ref, pm_out, val_out):
-    lba = ops_ref[0, 0]
-    old = ops_ref[0, 1]
-    ok = ops_ref[0, 2] != 0
-
-    @pl.when(ok & (old >= 0))
-    def _clear():
-        val_out[pl.ds(old, 1), :] = jnp.zeros((1, 1), jnp.int32)
-
-    @pl.when(ok)
-    def _unmap():
-        pm_out[pl.ds(lba, 1), :] = jnp.full((1, 1), -1, jnp.int32)
+    return page_map, lba_new, val_new.astype(valid.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -110,22 +155,9 @@ def apply_trim(
     enabled: jax.Array | bool = True,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    kk, b = valid.shape
-    ops = jnp.stack(
-        [lba, old_pm, jnp.asarray(enabled, jnp.int32)]
-    ).astype(jnp.int32)[None, :]
-    out = pl.pallas_call(
-        _apply_trim_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((page_map.shape[0], 1), jnp.int32),
-            jax.ShapeDtypeStruct((kk * b, 1), jnp.int32),
-        ),
-        input_output_aliases={1: 0, 2: 1},
-        interpret=interpret,
-    )(
-        ops,
-        page_map[:, None],
-        valid.reshape(-1, 1).astype(jnp.int32),
+    ops = _ops_row(lba, old_pm, jnp.int32(-1), enabled)
+    (val_new,) = _trim_op(interpret)(valid.astype(jnp.int32), ops)
+    page_map = page_map.at[lba].set(
+        jnp.where(ops[3] != 0, -1, page_map[lba])
     )
-    pm_new, val_new = out
-    return pm_new[:, 0], val_new[:, 0].astype(valid.dtype).reshape(kk, b)
+    return page_map, val_new.astype(valid.dtype)

@@ -40,7 +40,13 @@ def drive_mesh(n_dev: int) -> jax.sharding.Mesh:
     """
     devs = jax.devices()
     assert 1 <= n_dev <= len(devs), (n_dev, len(devs))
-    return jax.make_mesh((n_dev,), ("drives",), devices=devs[:n_dev])
+    # Auto axis: the executor's shard_map body is plain per-device code
+    # (jax.make_mesh defaults to Explicit axes, whose sharding-in-types
+    # rules reject the step's gathers)
+    return jax.make_mesh(
+        (n_dev,), ("drives",), devices=devs[:n_dev],
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
 
 
 def mesh_devices(mesh: jax.sharding.Mesh) -> int:

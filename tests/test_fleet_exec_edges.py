@@ -1,5 +1,5 @@
 """Executor edge paths: device resolution, ragged padding, the compiled-
-runner memo, fenced sub-batch resolution, and the persistent-cache guard.
+runner memo, fenced sub-batch resolution, and the compile-cache helper.
 
 These pin the failure-handling seams of ``fleet_exec`` that the happy-path
 fleet suites never reach: the import-order device trap only warns when the
@@ -7,11 +7,12 @@ host-device flag was never set; ``pad_batch`` must be a no-op at pad=0 and
 a pure row-0 replication otherwise; ``step_cache_clear`` must actually
 force a recompile; a poisoned sub-batch must surface as a
 ``SubbatchResolutionError`` carrying its partition key and drive ids after
-the healthy sub-batches resolved; and the on-disk compilation cache must
-refuse to arm itself on a jaxlib/backend combo known to corrupt the heap.
+the healthy sub-batches resolved; and the on-disk compilation cache lands
+where ``JAX_COMPILATION_CACHE_DIR`` says, else at a fixed checkout path.
 """
 
 import dataclasses
+import pathlib
 import warnings
 
 import jax
@@ -25,6 +26,7 @@ from repro.core import managers as M
 from repro.core import workloads as W
 from repro.core.fleet import DriveSpec, simulate_fleet
 from repro.core.ssd import Geometry
+from repro.utils import compile_cache
 
 GEOM = Geometry(n_luns=4, blocks_per_lun=32, pages_per_block=8, lba_pba=0.7)
 
@@ -43,8 +45,10 @@ class TestResolveDevices:
             warnings.simplefilter("error")  # the flag IS set: no warning
             assert fe.resolve_devices("auto") == n
         assert fe.resolve_devices(2) == 2
-        assert fe.resolve_devices(99) == n
         assert fe.resolve_devices("2") == 2
+        # more devices than exist is an error, never a quiet smaller mesh
+        with pytest.raises(ValueError, match="device"):
+            fe.resolve_devices(99)
 
     def test_auto_warns_on_unset_flag(self, monkeypatch):
         """jax initialized before --xla_force_host_platform_device_count:
@@ -175,65 +179,39 @@ class TestSubbatchResolution:
         assert "poisoned device buffer" in msg
 
 
-@pytest.mark.fault
-class TestPersistentCacheGuard:
-    """enable_persistent_compilation_cache must refuse to arm the on-disk
-    cache on a jaxlib/backend combo known to corrupt the heap (see the
-    hazard note on the function), unless explicitly forced."""
+class TestCompileCache:
+    """repro.utils.compile_cache.enable: the env var wins untouched, else a
+    fixed checkout-root directory. jax.config.update is recorded, never
+    applied, so the test run writes nothing into the checkout."""
 
-    def _arm(self, monkeypatch):
-        if jax.default_backend() != "cpu":  # pragma: no cover
-            pytest.skip("the known-bad combos are all XLA:CPU")
-        import jaxlib
-
-        # pin the CURRENT jaxlib as known-bad so the test is meaningful
-        # even after a toolchain bump
+    @pytest.fixture
+    def calls(self, monkeypatch):
+        recorded = []
         monkeypatch.setattr(
-            fe, "_CACHE_BAD_JAXLIB_CPU",
-            fe._CACHE_BAD_JAXLIB_CPU + (jaxlib.__version__,),
+            jax.config, "update", lambda *a: recorded.append(a)
         )
-        monkeypatch.setattr(fe, "_PERSISTENT_WIRED", False)
-        monkeypatch.delenv("REPRO_JAX_CACHE_FORCE", raising=False)
-        calls = []
-        monkeypatch.setattr(
-            fe.jax.config, "update", lambda *a: calls.append(a)
-        )
-        return calls
+        return recorded
 
-    def test_container_combo_is_flagged(self):
-        """The pinned container toolchain (jaxlib 0.4.36/0.4.37 on
-        XLA:CPU) is exactly the bisected combo: the hazard fires here."""
-        import jaxlib
+    def test_env_dir_is_used_as_is(self, monkeypatch, calls, tmp_path):
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert calls == []  # jax reads the variable itself; no other dir
 
-        if (jax.default_backend() != "cpu"
-                or jaxlib.__version__ not in fe._CACHE_BAD_JAXLIB_CPU):
-            pytest.skip("not a known-bad jaxlib/backend combo")
-        hazard = fe._persistent_cache_hazard()
-        assert hazard is not None and "heap" in hazard
+    def test_unset_env_uses_checkout_root(self, monkeypatch, calls):
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        path = compile_cache.enable()
+        root = pathlib.Path(__file__).resolve().parent.parent
+        assert path == str(root / ".jax_cache")
+        assert calls == [("jax_compilation_cache_dir", path)]
 
-    def test_refuses_on_known_bad_combo(self, monkeypatch, tmp_path):
-        calls = self._arm(monkeypatch)
-        with pytest.warns(RuntimeWarning, match="refusing to enable"):
-            out = fe.enable_persistent_compilation_cache(str(tmp_path))
-        assert out == str(tmp_path)  # path still reported, never wired
-        assert calls == []
-        assert fe._PERSISTENT_WIRED is False
+    def test_idempotent(self, monkeypatch, calls):
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        first = compile_cache.enable()
+        assert compile_cache.enable() == first
+        assert {c for c in calls} == {("jax_compilation_cache_dir", first)}
 
-    def test_force_override_wires(self, monkeypatch, tmp_path):
-        calls = self._arm(monkeypatch)
-        monkeypatch.setenv("REPRO_JAX_CACHE_FORCE", "1")
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            out = fe.enable_persistent_compilation_cache(str(tmp_path))
-        assert out == str(tmp_path)
-        assert ("jax_compilation_cache_dir", str(tmp_path)) in calls
-        assert fe._PERSISTENT_WIRED is True
-
-    def test_clean_combo_wires(self, monkeypatch, tmp_path):
-        calls = self._arm(monkeypatch)
-        monkeypatch.setattr(fe, "_persistent_cache_hazard", lambda: None)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            fe.enable_persistent_compilation_cache(str(tmp_path))
-        assert ("jax_compilation_cache_dir", str(tmp_path)) in calls
-        assert fe._PERSISTENT_WIRED is True
+    def test_checkout_cache_is_ignored_by_git(self):
+        root = pathlib.Path(__file__).resolve().parent.parent
+        ignored = (root / ".gitignore").read_text().split()
+        assert ".jax_cache/" in ignored
+        assert compile_cache.CHECKOUT_CACHE.parent == root
