@@ -48,6 +48,14 @@ Execution architecture (core/fleet_exec.py owns the device side):
   the first jax import — the device count is locked at backend init (that
   is also why ``devices="auto"`` from a jax-already-imported entry point
   warns instead of silently running on 1 device).
+* **Host spans** — each sub-batch's host work is wrapped in
+  ``jax.profiler.TraceAnnotation`` spans, which land in a profiler trace
+  on the same clock as the device ops: ``fleet.build`` (the drive loop:
+  ``build_drive``, contexts, policies, params, streams), ``fleet.stack``
+  (stacking, host-to-device copies, filler padding), ``fleet.dispatch``
+  (runner lookup and call; a memo miss compiles here) and
+  ``fleet.resolve`` (the blocking host copy of the traces and the trim of
+  filler rows). Without an active profiler they cost nothing.
 
 Degraded drives are inert lanes, like filler drives: the fault-injection
 layer (see simulator.py's fault section) freezes a drive whose spare pool
@@ -493,109 +501,105 @@ def simulate_fleet(
         per_drive_interval = (
             len({s.mcfg.interval_frac for s in sub}) > 1
         )
-        sts, policies, page_rates, params, streams = [], [], [], [], []
-        page_groups = []
-        n_groups_max = 1
-        for s in sub:
-            st, n_groups, assumed_p, fdp_rate, rates, pg0 = build_drive(
-                geom, s.mcfg, list(s.phases),
-                init_p_from_phase=init_p_from_phase,
-                g_max=g_max, use_bloom=use_bloom,
-            )
-            page_groups.append(pg0)
-            n_groups_max = max(n_groups_max, n_groups)
-            ctx_d = SimContext(
-                geom, dataclasses.replace(s.mcfg, max_groups=g_max),
-                n_groups, use_bloom=use_bloom,
-                use_movement=use_movement, can_demote=can_demote,
-                use_dynamic=use_dynamic, use_closed_alloc=use_closed,
-                with_faults=with_faults,
-            )
-            policy = policy_from_config(ctx_d, assumed_p, fdp_rate)
-            # the drive keeps its OWN dynamic-group cap in the padded arrays
-            policy["max_groups"] = jnp.asarray(s.mcfg.max_groups, jnp.int32)
-            sts.append(st)
-            policies.append(policy)
-            page_rates.append(
-                np.concatenate(
-                    [rates,
-                     np.zeros((p_max - len(rates),) + rates.shape[1:],
-                              rates.dtype)]
+        with jax.profiler.TraceAnnotation("fleet.build"):
+            sts, policies, page_rates, params, streams = [], [], [], [], []
+            page_groups = []
+            n_groups_max = 1
+            for s in sub:
+                st, n_groups, assumed_p, fdp_rate, rates, pg0 = build_drive(
+                    geom, s.mcfg, list(s.phases),
+                    init_p_from_phase=init_p_from_phase,
+                    g_max=g_max, use_bloom=use_bloom,
                 )
-            )
-            params.append(
-                phase_param_arrays(list(s.phases), g_max=g_wl, p_max=p_max)
-            )
-            if sampler == "numpy" and with_trim:
-                # exact host op streams (Phase.sample_ops: pure-write
-                # phases consume the draws Phase.sample would)
-                rng = np.random.default_rng(s.seed)
-                pairs = [ph.sample_ops(rng) for ph in s.phases]
-                streams.append((
-                    jnp.asarray(np.concatenate([o for o, _ in pairs]),
-                                jnp.int32),
-                    jnp.asarray(np.concatenate([l for _, l in pairs]),
-                                jnp.int32),
-                ))
-            elif sampler == "numpy":
-                rng = np.random.default_rng(s.seed)
-                streams.append(
-                    jnp.asarray(
-                        np.concatenate([ph.sample(rng) for ph in s.phases]),
-                        jnp.int32,
+                page_groups.append(pg0)
+                n_groups_max = max(n_groups_max, n_groups)
+                ctx_d = SimContext(
+                    geom, dataclasses.replace(s.mcfg, max_groups=g_max),
+                    n_groups, use_bloom=use_bloom,
+                    use_movement=use_movement, can_demote=can_demote,
+                    use_dynamic=use_dynamic, use_closed_alloc=use_closed,
+                    with_faults=with_faults,
+                )
+                policy = policy_from_config(ctx_d, assumed_p, fdp_rate)
+                # the drive keeps its OWN dynamic-group cap in the padded
+                # arrays
+                policy["max_groups"] = jnp.asarray(
+                    s.mcfg.max_groups, jnp.int32)
+                sts.append(st)
+                policies.append(policy)
+                page_rates.append(
+                    np.concatenate(
+                        [rates,
+                         np.zeros((p_max - len(rates),) + rates.shape[1:],
+                                  rates.dtype)]
                     )
                 )
-            else:
-                # key on the seed ALONE, mirroring the numpy sampler: a
-                # drive's stream is a function of (phases, seed), never of
-                # its position in the specs list (same seed + same phases
-                # → common random numbers for paired policy comparisons)
-                streams.append(jax.random.PRNGKey(s.seed))
+                params.append(
+                    phase_param_arrays(list(s.phases), g_max=g_wl, p_max=p_max)
+                )
+                if sampler == "numpy" and with_trim:
+                    # exact host op streams (Phase.sample_ops: pure-write
+                    # phases consume the draws Phase.sample would)
+                    rng = np.random.default_rng(s.seed)
+                    pairs = [ph.sample_ops(rng) for ph in s.phases]
+                    streams.append((
+                        jnp.asarray(np.concatenate([o for o, _ in pairs]),
+                                    jnp.int32),
+                        jnp.asarray(np.concatenate([l for _, l in pairs]),
+                                    jnp.int32),
+                    ))
+                elif sampler == "numpy":
+                    rng = np.random.default_rng(s.seed)
+                    streams.append(
+                        jnp.asarray(
+                            np.concatenate(
+                                [ph.sample(rng) for ph in s.phases]),
+                            jnp.int32,
+                        )
+                    )
+                else:
+                    # key on the seed ALONE, mirroring the numpy sampler: a
+                    # drive's stream is a function of (phases, seed), never of
+                    # its position in the specs list (same seed + same phases
+                    # → common random numbers for paired policy comparisons)
+                    streams.append(jax.random.PRNGKey(s.seed))
 
-        ctx = SimContext(
-            geom,
-            # the shared ctx keeps the SUB-BATCH's interval_frac so ctx.h
-            # (the scalar predicate) is exact on the homogeneous fast path;
-            # td_mode/movement/dynamic/alloc mirror the partition key (the
-            # simulator dispatches the detector and the interval machinery
-            # from these statics at trace time)
-            dataclasses.replace(
-                base, name="fleet", max_groups=g_max,
-                interval_frac=sub[0].mcfg.interval_frac,
-                movement_ops=use_movement, td_mode=td_mode,
-                dynamic_groups=use_dynamic,
-                alloc_mode=sub[0].mcfg.alloc_mode,
-                # normalize per-drive fault knobs out of the shared ctx:
-                # rates/limits/seeds are traced policy data, so the memoized
-                # runner key must depend only on with_faults (structure) and
-                # erase_max_retries (shared static), never on which rates
-                # this particular fleet happens to sweep
-                fault_rate=0.0, fault_rate_worn=1.0,
-                endurance_pe_limit=0, spare_blocks=None, fault_seed=0,
-            ),
-            n_groups_max,
-            use_bloom=use_bloom,
-            gc_impl=gc_impl,
-            per_drive_interval=per_drive_interval,
-            fast_path=fast_path,
-            use_movement=use_movement,
-            can_demote=can_demote,
-            use_dynamic=use_dynamic,
-            use_closed_alloc=use_closed,
-            trace_every=trace_every,
-            unroll=unroll,
-            with_trim=with_trim,
-            with_faults=with_faults,
-        )
-        args = (
-            _stack(sts),
-            _stack(streams),
-            {k: jnp.asarray(np.stack([p[k] for p in params]))
-             for k in params[0]},
-            jnp.asarray(np.stack(page_rates)),
-            jnp.asarray(np.stack(page_groups)),
-            _stack(policies),
-        )
+            ctx = SimContext(
+                geom,
+                # the shared ctx keeps the SUB-BATCH's interval_frac so ctx.h
+                # (the scalar predicate) is exact on the homogeneous fast path;
+                # td_mode/movement/dynamic/alloc mirror the partition key (the
+                # simulator dispatches the detector and the interval machinery
+                # from these statics at trace time)
+                dataclasses.replace(
+                    base, name="fleet", max_groups=g_max,
+                    interval_frac=sub[0].mcfg.interval_frac,
+                    movement_ops=use_movement, td_mode=td_mode,
+                    dynamic_groups=use_dynamic,
+                    alloc_mode=sub[0].mcfg.alloc_mode,
+                    # normalize per-drive fault knobs out of the shared ctx:
+                    # rates/limits/seeds are traced policy data, so the
+                    # memoized runner key must depend only on with_faults
+                    # (structure) and erase_max_retries (shared static),
+                    # never on which rates this particular fleet happens to
+                    # sweep
+                    fault_rate=0.0, fault_rate_worn=1.0,
+                    endurance_pe_limit=0, spare_blocks=None, fault_seed=0,
+                ),
+                n_groups_max,
+                use_bloom=use_bloom,
+                gc_impl=gc_impl,
+                per_drive_interval=per_drive_interval,
+                fast_path=fast_path,
+                use_movement=use_movement,
+                can_demote=can_demote,
+                use_dynamic=use_dynamic,
+                use_closed_alloc=use_closed,
+                trace_every=trace_every,
+                unroll=unroll,
+                with_trim=with_trim,
+                with_faults=with_faults,
+            )
         # mesh dispatch: every sub-batch uses the full resolved device
         # count; raggedness is absorbed by inert filler drives (per-device
         # wall-clock is ceil(B/d) scans with or without the pad). Dispatch
@@ -604,10 +608,22 @@ def simulate_fleet(
         # sub-batch's device execution (the pipeline).
         d = min(n_dev, len(sub))
         pad = (-len(sub)) % d
-        if pad:
-            args = pad_batch(args, pad)
-        runner = subbatch_runner(ctx, n_total, sampler == "jax", d)
-        pending.append((key, idx, runner(*args), pad))
+        with jax.profiler.TraceAnnotation("fleet.stack"):
+            args = (
+                _stack(sts),
+                _stack(streams),
+                {k: jnp.asarray(np.stack([p[k] for p in params]))
+                 for k in params[0]},
+                jnp.asarray(np.stack(page_rates)),
+                jnp.asarray(np.stack(page_groups)),
+                _stack(policies),
+            )
+            if pad:
+                args = pad_batch(args, pad)
+        # a runner memo miss compiles inside this span
+        with jax.profiler.TraceAnnotation("fleet.dispatch"):
+            runner = subbatch_runner(ctx, n_total, sampler == "jax", d)
+            pending.append((key, idx, runner(*args), pad))
         exec_meta.append({"drives": len(sub), "devices": d, "padding": pad})
 
     # resolve pass: block on each sub-batch's outputs (host↔device transfer
@@ -621,16 +637,17 @@ def simulate_fleet(
     failures: list[SubbatchFailure] = []
     for k_i, (key, idx, out, pad) in enumerate(pending):
         try:
-            st_f, trace, lbas = out
-            b = len(idx)
-            app[idx], mig[idx] = (
-                np.asarray(trace[0][:b]), np.asarray(trace[1][:b])
-            )
-            if return_lbas:
-                lbas_out[idx] = np.asarray(lbas[:b])
-            if pad:
-                st_f = jax.tree_util.tree_map(lambda a: a[:b], st_f)
-            shards.append((idx, st_f))
+            with jax.profiler.TraceAnnotation("fleet.resolve"):
+                st_f, trace, lbas = out
+                b = len(idx)
+                app[idx], mig[idx] = (
+                    np.asarray(trace[0][:b]), np.asarray(trace[1][:b])
+                )
+                if return_lbas:
+                    lbas_out[idx] = np.asarray(lbas[:b])
+                if pad:
+                    st_f = jax.tree_util.tree_map(lambda a: a[:b], st_f)
+                shards.append((idx, st_f))
         except Exception as e:  # noqa: BLE001 — rewrapped with context below
             failures.append(SubbatchFailure(
                 subbatch=k_i, part_key=key, drive_ids=tuple(idx),

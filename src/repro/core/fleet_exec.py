@@ -190,12 +190,13 @@ def subbatch_runner(ctx: SimContext, n_total: int, on_device_sampler: bool,
     def run_one(st, stream, params, page_rate, page_group0, policy):
         ops = None
         if on_device_sampler:
-            if ctx.with_trim:
-                ops, lbas = sample_phases_device(
-                    stream, params, n_total, with_ops=True
-                )
-            else:
-                lbas = sample_phases_device(stream, params, n_total)
+            with jax.named_scope("ssd.sampler"):
+                if ctx.with_trim:
+                    ops, lbas = sample_phases_device(
+                        stream, params, n_total, with_ops=True
+                    )
+                else:
+                    lbas = sample_phases_device(stream, params, n_total)
         elif ctx.with_trim:
             ops, lbas = stream
         else:

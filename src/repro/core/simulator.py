@@ -196,6 +196,26 @@ Architecture (post bulk-GC refactor):
   (``SimContext.per_drive_interval=False``) so the §5.1 bookkeeping
   remains a real every-h-steps branch, not a per-step select.
 
+Phase scopes. The step's phases are traced inside ``jax.named_scope``
+names, which XLA keeps as op metadata (``op_name``) and a profiler trace
+of the compiled step reports per device op; they change no instruction.
+Every name starts with ``ssd.``:
+
+* ``ssd.append`` — invalidate, the target group's liveness check,
+  append and the per-write counters (the fast branch of the split write
+  too);
+* ``ssd.detector`` — the §5.6 bloom (or FDP) temperature detector;
+* ``ssd.gc`` — the §5.4 GC predicate and :func:`_gc_one`, refined by
+  ``ssd.victim`` (:func:`_select_victim`) and ``ssd.drain`` (the drains);
+* ``ssd.valve`` — the emergency-valve loop; ``ssd.movement`` — the §5.3
+  movement GC; ``ssd.interval`` — the §5.1/§5.5/§5.2 interval update;
+* ``ssd.trim`` — :func:`_trim_page`;
+* ``ssd.chunk`` — :func:`scan_writes`'s inner loop over ``trace_every``
+  events; ``ssd.sampler`` — the on-device stream sampler of the fleet.
+
+An op belongs to the outermost phase on its path, so a valve's or a
+movement's GC counts as valve or movement.
+
 GC migrations re-enter the same write semantics (so migrated pages can be
 demoted by the detector, as in Listing 1/3 of the paper).
 """
@@ -444,6 +464,23 @@ _STEP_FIELDS = tuple(
 _OP_FIELDS = tuple(SimState.__dataclass_fields__)
 
 
+def _scoped(name):
+    """Trace the decorated step piece inside ``jax.named_scope(name)``: its
+    device ops carry the step phase in their op metadata, where a profiler
+    trace of the compiled step finds it (the names are listed in the
+    module docstring's "Phase scopes")."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
+
+
 def _fields_of(st: SimState, fields):
     return tuple(getattr(st, f) for f in fields)
 
@@ -571,6 +608,7 @@ def _write_page(ctx: SimContext, st: SimState, lba, g, *, is_migration: bool,
     return st.replace(**updates)
 
 
+@_scoped("ssd.append")
 def _invalidate(ctx: SimContext, st: SimState, lba):
     b = ctx.geom.pages_per_block
     pm = st.page_map[lba]
@@ -592,6 +630,7 @@ def _invalidate(ctx: SimContext, st: SimState, lba):
     return st, jnp.where(has, old_g, 0)
 
 
+@_scoped("ssd.append")
 def _invalidate_counts(ctx: SimContext, st: SimState, lba):
     """The counter half of :func:`_invalidate`: live/grp_size decrements and
     the old-group lookup, WITHOUT the valid-bit clear.
@@ -617,6 +656,7 @@ def _invalidate_counts(ctx: SimContext, st: SimState, lba):
     return st, jnp.where(has, old_g, 0), pm
 
 
+@_scoped("ssd.append")
 def _clear_valid(ctx: SimContext, st: SimState, pm):
     """Complete a deferred invalidate: clear the old slot's valid bit."""
     b = ctx.geom.pages_per_block
@@ -737,6 +777,7 @@ def _erase_fault_retire(ctx: SimContext, st: SimState, victim, g, policy):
 GC_W_GREEDY = (1.0, 0.0, 0.0, 0.0)
 
 
+@_scoped("ssd.victim")
 def _select_victim(ctx: SimContext, st: SimState, g, gc_w):
     """Multi-objective victim selection: one traced score, maximised.
 
@@ -769,6 +810,7 @@ def _select_victim(ctx: SimContext, st: SimState, g, gc_w):
     return victim, ok
 
 
+@_scoped("ssd.drain")
 def _gc_drain_bulk(ctx: SimContext, st: SimState, victim, g, policy, rate_fn):
     """Vectorized victim drain: migrate every live page in one shot.
 
@@ -1006,6 +1048,7 @@ def _gc_drain_bulk(ctx: SimContext, st: SimState, victim, g, policy, rate_fn):
     )
 
 
+@_scoped("ssd.drain")
 def _gc_drain_bulk_static(ctx: SimContext, st: SimState, victim, g):
     """Single-target specialization of :func:`_gc_drain_bulk` for
     static-detector contexts (``ctx.can_demote=False``).
@@ -1114,6 +1157,7 @@ def _gc_drain_bulk_static(ctx: SimContext, st: SimState, victim, g):
     )
 
 
+@_scoped("ssd.drain")
 def _gc_drain_reference(ctx: SimContext, st: SimState, victim, g, demote_fn):
     """The pre-refactor per-page drain (16-step fori of single-page writes).
 
@@ -1169,6 +1213,7 @@ def _gc_drain_reference(ctx: SimContext, st: SimState, victim, g, demote_fn):
     )
 
 
+@_scoped("ssd.gc")
 def _gc_one(ctx: SimContext, st: SimState, g, policy, rate_fn, gc_w,
             enabled=True):
     """GC one victim in group g; migrate live pages via the bulk drain.
@@ -1503,15 +1548,17 @@ def _target_group_app(ctx: SimContext, st: SimState, lba, cur_g, policy, rate_fn
     if td == "fdp":
         # fixed assumed per-page rate bands: promote if ≥2× the group's
         # assumed rate (paper §5/§6: FDP's fixed-order assumption)
-        r = rate_fn(st, lba)
-        promote = r > 2.0 * policy["fdp_rate"][cur_g]
-        nb = _neighbor_hotter(_hit_rates(st), st.grp_active, cur_g)
-        return st, jnp.where(promote, nb, cur_g).astype(jnp.int32)
+        with jax.named_scope("ssd.detector"):
+            r = rate_fn(st, lba)
+            promote = r > 2.0 * policy["fdp_rate"][cur_g]
+            nb = _neighbor_hotter(_hit_rates(st), st.grp_active, cur_g)
+            return st, jnp.where(promote, nb, cur_g).astype(jnp.int32)
     assert td == "bloom", td
     # bloom (§5.6): in both filters → promote
-    st, in_both = _bloom_update(ctx, st, lba, cur_g)
-    nb = _neighbor_hotter(_hit_rates(st), st.grp_active, cur_g)
-    return st, jnp.where(in_both, nb, cur_g).astype(jnp.int32)
+    with jax.named_scope("ssd.detector"):
+        st, in_both = _bloom_update(ctx, st, lba, cur_g)
+        nb = _neighbor_hotter(_hit_rates(st), st.grp_active, cur_g)
+        return st, jnp.where(in_both, nb, cur_g).astype(jnp.int32)
 
 
 def _target_group_gc(ctx: SimContext, st: SimState, lba, cur_g, policy, rate_fn):
@@ -1596,13 +1643,14 @@ def _step_tail(ctx: SimContext, st: SimState, lba, t, g, policy, lookup):
 
     # GC when the group needs a new block it is not entitled to, or the
     # pool is at reserve.
-    blk = st.active_blk[g]
-    needs_block = jnp.where(
-        blk >= 0, st.fill[jnp.maximum(blk, 0)] >= b, True
-    )
-    over_budget = st.grp_phys[g] >= st.grp_alloc[g]
-    low_pool = st.free_blocks <= mcfg.gc_reserve_blocks
-    do_gc = needs_block & (over_budget | low_pool)
+    with jax.named_scope("ssd.gc"):
+        blk = st.active_blk[g]
+        needs_block = jnp.where(
+            blk >= 0, st.fill[jnp.maximum(blk, 0)] >= b, True
+        )
+        over_budget = st.grp_phys[g] >= st.grp_alloc[g]
+        low_pool = st.free_blocks <= mcfg.gc_reserve_blocks
+        do_gc = needs_block & (over_budget | low_pool)
     st = _gc_one(ctx, st, g, policy, lookup, policy["gc_w"], enabled=do_gc)
 
     # emergency valve: if the pool is (nearly) empty, greedily reclaim
@@ -1624,25 +1672,28 @@ def _step_tail(ctx: SimContext, st: SimState, lba, t, g, policy, lookup):
             tries + 1,
         )
 
-    st, _ = _while_fields(needs_air, reclaim, st, 0, _gc_fields(ctx))
+    with jax.named_scope("ssd.valve"):
+        st, _ = _while_fields(needs_air, reclaim, st, 0, _gc_fields(ctx))
 
-    st = _write_page(ctx, st, lba, g, is_migration=False)
-    st = st.replace(
-        n_app=st.n_app + 1,
-        grp_writes=st.grp_writes.at[g].add(1),
-    )
+    with jax.named_scope("ssd.append"):
+        st = _write_page(ctx, st, lba, g, is_migration=False)
+        st = st.replace(
+            n_app=st.n_app + 1,
+            grp_writes=st.grp_writes.at[g].add(1),
+        )
 
     # movement operations (§5.3): one compaction GC per step on the most
     # surplus group, donating the redeemed block to the pool. Structurally
     # absent when the context rules movement out (ctx.use_movement=False).
     if ctx.use_movement:
-        g_s = jnp.argmax(st.grp_surplus)
-        pool_ok = st.free_blocks >= 2  # migration headroom
-        st = _gc_one(
-            ctx, st, g_s, policy, lookup, policy["gc_w"],
-            enabled=policy["movement_ops"] & (st.grp_surplus[g_s] >= 1)
-            & pool_ok,
-        )
+        with jax.named_scope("ssd.movement"):
+            g_s = jnp.argmax(st.grp_surplus)
+            pool_ok = st.free_blocks >= 2  # migration headroom
+            st = _gc_one(
+                ctx, st, g_s, policy, lookup, policy["gc_w"],
+                enabled=policy["movement_ops"] & (st.grp_surplus[g_s] >= 1)
+                & pool_ok,
+            )
 
     # interval completion (§5.1); t+1 == n_app after this write, so the
     # predicate is exactly (n_app % h == 0). With a fleet-shared h it is
@@ -1653,23 +1704,24 @@ def _step_tail(ctx: SimContext, st: SimState, lba, t, g, policy, lookup):
     # write clock — same value under a pure-write stream, where
     # st.n_app == t + 1 at this point.
     h = policy["h"] if ctx.per_drive_interval else ctx.h
-    if ctx.with_trim:
-        is_interval = (st.n_app % h) == 0
-    else:
-        is_interval = ((t + 1) % h) == 0
     interval_fields = _INTERVAL_FIELDS
     if ctx.with_faults:
         # §5.2 merges relabel retired counts (see _maybe_create_or_merge)
         interval_fields = interval_fields + ("grp_retired",)
-    st = _cond_fields(
-        is_interval,
-        lambda s: _interval_update(ctx, s, policy),
-        st,
-        interval_fields,
-    )
-    return st
+    with jax.named_scope("ssd.interval"):
+        if ctx.with_trim:
+            is_interval = (st.n_app % h) == 0
+        else:
+            is_interval = ((t + 1) % h) == 0
+        return _cond_fields(
+            is_interval,
+            lambda s: _interval_update(ctx, s, policy),
+            st,
+            interval_fields,
+        )
 
 
+@_scoped("ssd.trim")
 def _trim_page(ctx: SimContext, st: SimState, lba):
     """The op-stream TRIM step: unmap ``lba`` and kill its physical slot.
 
@@ -1759,6 +1811,7 @@ def make_step(ctx: SimContext, policy, rate_fn, page_group0=None):
         assert page_group0 is not None, "op-stream step needs page_group0"
         page_group0 = jnp.asarray(page_group0, jnp.int32)
 
+    @_scoped("ssd.append")
     def resolve_group(st, old_g, had_mapping, lba):
         # a write that re-maps a trimmed page inherits the workload's
         # layout group (first active group if dynamic-mode merging has
@@ -1778,7 +1831,8 @@ def make_step(ctx: SimContext, policy, rate_fn, page_group0=None):
         else:
             st, old_g = _invalidate(ctx, st, lba)
         st, g = _target_group_app(ctx, st, lba, old_g, policy, lookup)
-        g = jnp.where(st.grp_active[g], g, old_g)
+        with jax.named_scope("ssd.append"):
+            g = jnp.where(st.grp_active[g], g, old_g)
         return _step_tail(ctx, st, lba, t, g, policy, lookup)
 
     def split_write(st, lba, t, lookup):
@@ -1786,7 +1840,8 @@ def make_step(ctx: SimContext, policy, rate_fn, page_group0=None):
         if ctx.with_trim:
             old_g = resolve_group(st, old_g, old_pm >= 0, lba)
         st, g = _target_group_app(ctx, st, lba, old_g, policy, lookup)
-        g = jnp.where(st.grp_active[g], g, old_g)
+        with jax.named_scope("ssd.append"):
+            g = jnp.where(st.grp_active[g], g, old_g)
 
         # O(1) heavy-path predicates. Exactness argument per term:
         #  * room in the active block → _step_tail's do_gc and the
@@ -1820,6 +1875,7 @@ def make_step(ctx: SimContext, policy, rate_fn, page_group0=None):
             st = _clear_valid(ctx, st, old_pm)
             return _step_tail(ctx, st, lba, t, g, policy, lookup)
 
+        @_scoped("ssd.append")
         def fast_path(st):
             slot = st.fill[blk_c]
             page_map, slot_lba, valid = apply_write(
@@ -1930,7 +1986,10 @@ def scan_writes(ctx: SimContext, step, st: SimState, lbas, ts, ops=None):
         return s, None
 
     def chunk(s, xs):
-        s, _ = jax.lax.scan(inner, s, xs, unroll=min(ctx.unroll, e))
+        # the chunk's loop: a profiler trace reads one ssd.chunk loop event
+        # per trace_every events, and the step's phases inside it
+        with jax.named_scope("ssd.chunk"):
+            s, _ = jax.lax.scan(inner, s, xs, unroll=min(ctx.unroll, e))
         return s, (s.n_app, s.n_mig)
 
     xs = tuple(c.reshape(t_total // e, e) for c in cols)
