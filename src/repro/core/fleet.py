@@ -95,6 +95,7 @@ from repro.core.fleet_exec import (
     SubbatchResolutionError,
     pad_batch,
     resolve_devices,
+    split_step,
     subbatch_runner,
 )
 from repro.core.managers import RunResult, build_drive
@@ -393,7 +394,7 @@ def simulate_fleet(
     return_lbas: bool = False,
     devices: int | str | None = None,
     gc_impl: str = "bulk",
-    fast_path: bool = False,
+    fast_path: bool | None = None,
     trace_every: int = 1,
     unroll: int = 1,
     ops_stream: bool | None = None,
@@ -418,8 +419,8 @@ def simulate_fleet(
     device count, or its drive count if smaller: ragged sub-batches are
     padded with inert filler drives (free: the pad fills otherwise-idle
     lanes) and the filler rows never surface in results.
-    ``FleetResult.exec_meta`` records drives/devices/padding per
-    sub-batch. Results are
+    ``FleetResult.exec_meta`` records drives/devices/padding and the step
+    engine (``"split"`` or ``"single"``) per sub-batch. Results are
     bit-identical across device counts. NOTE: on CPU the visible device
     count is locked at jax backend init — see
     ``repro.utils.hostdev.force_host_device_count``.
@@ -428,13 +429,16 @@ def simulate_fleet(
     SimContext — the bulk-vs-reference equivalence suite runs whole fleets
     under both.
 
-    fast_path: step engine. The fleet default is the single-path step
-    (False): under vmap a lax.cond executes BOTH branches and selects, so
-    the split engine's lean branch is pure extra work here — it pays off
-    under plain jit (managers.simulate, accelerator per-core scans), where
-    the heavy tail is a real untaken branch. Both engines are elementwise-
-    identical (tests/test_write_engine.py), so this is a pure scheduling
-    knob. trace_every / unroll: trace stride and scan unroll
+    fast_path: step engine. None (default) picks it per sub-batch by its
+    lanes per device (``fleet_exec.split_step``): a device holding one
+    drive scans it without vmap and takes the split step, whose heavy
+    tail (GC, valve, movement, §5.1 interval) is a real branch entered
+    only when an O(1) predicate trips; vmapped lanes take the single-path
+    step, since under vmap a lax.cond runs BOTH branches and selects.
+    True / False force the split / single-path step everywhere. Both
+    engines are elementwise-identical (tests/test_write_engine.py), so
+    this is a pure scheduling knob; ``exec_meta`` records the engine of
+    each sub-batch. trace_every / unroll: trace stride and scan unroll
     (simulator.scan_writes); trace_every must divide n_total, and app/mig
     come back [B, n_total // trace_every].
 
@@ -501,6 +505,16 @@ def simulate_fleet(
         per_drive_interval = (
             len({s.mcfg.interval_frac for s in sub}) > 1
         )
+        # mesh dispatch: every sub-batch uses the full resolved device
+        # count; raggedness is absorbed by inert filler drives (per-device
+        # wall-clock is ceil(B/d) scans with or without the pad), and the
+        # lanes each device scans pick the step engine. Dispatch is async —
+        # the runner call returns once enqueued, so the next iteration's
+        # host-side build_drive/stacking overlaps this sub-batch's device
+        # execution (the pipeline).
+        d = min(n_dev, len(sub))
+        pad = (-len(sub)) % d
+        split = split_step(fast_path, (len(sub) + pad) // d)
         with jax.profiler.TraceAnnotation("fleet.build"):
             sts, policies, page_rates, params, streams = [], [], [], [], []
             page_groups = []
@@ -590,7 +604,7 @@ def simulate_fleet(
                 use_bloom=use_bloom,
                 gc_impl=gc_impl,
                 per_drive_interval=per_drive_interval,
-                fast_path=fast_path,
+                fast_path=split,
                 use_movement=use_movement,
                 can_demote=can_demote,
                 use_dynamic=use_dynamic,
@@ -600,14 +614,6 @@ def simulate_fleet(
                 with_trim=with_trim,
                 with_faults=with_faults,
             )
-        # mesh dispatch: every sub-batch uses the full resolved device
-        # count; raggedness is absorbed by inert filler drives (per-device
-        # wall-clock is ceil(B/d) scans with or without the pad). Dispatch
-        # is async — the runner call returns once enqueued, so the next
-        # iteration's host-side build_drive/stacking overlaps this
-        # sub-batch's device execution (the pipeline).
-        d = min(n_dev, len(sub))
-        pad = (-len(sub)) % d
         with jax.profiler.TraceAnnotation("fleet.stack"):
             args = (
                 _stack(sts),
@@ -624,7 +630,8 @@ def simulate_fleet(
         with jax.profiler.TraceAnnotation("fleet.dispatch"):
             runner = subbatch_runner(ctx, n_total, sampler == "jax", d)
             pending.append((key, idx, runner(*args), pad))
-        exec_meta.append({"drives": len(sub), "devices": d, "padding": pad})
+        exec_meta.append({"drives": len(sub), "devices": d, "padding": pad,
+                          "engine": "split" if split else "single"})
 
     # resolve pass: block on each sub-batch's outputs (host↔device transfer
     # happens here, after every sub-batch has been enqueued) and strip the

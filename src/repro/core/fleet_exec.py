@@ -6,13 +6,20 @@ arrays and the devices:
 * **Sharding** — each sub-batch runs as ``jit(shard_map(vmap(run_one)))``
   over :func:`repro.launch.mesh.drive_mesh`'s ``"drives"`` axis. Drives are
   independent lanes, so each device executes the plain vmapped scan over
-  its slice of the batch (a device holding one drive scans it without the
-  vmap, so the step's conditionals stay real branches instead of selects
-  over both sides); numerics are bit-identical to the single-device
+  its slice of the batch; numerics are bit-identical to the single-device
   ``jit(vmap(...))`` path (which remains the ``n_dev == 1`` special case —
   no mesh, no collective, same trace). The legacy ``pmap(vmap(...))``
   executor is gone: shard_map composes with jit (one dispatch, donation,
   the compilation cache) where pmap was its own retired code path.
+
+* **Lanes per device pick the scan and the step** (:func:`split_step`).
+  A device holding one drive scans it without the vmap, so the step's
+  conditionals are real branches, and it builds the split step
+  (``SimContext.fast_path=True``): a write runs the GC / valve / movement
+  / interval tail only when an O(1) predicate says it may need it. Under
+  vmap a cond is a select that runs both branches, so a vmapped
+  sub-batch keeps the single-path step. Both engines give the same
+  values, element for element.
 
 * **Padding** — a sub-batch whose size is not a multiple of the device
   count is padded with inert filler drives (copies of the sub-batch's
@@ -33,8 +40,10 @@ arrays and the devices:
 
 * **Compiled-step cache** — runners are memoized on
   ``(SimContext, scan length, sampler kind, n_dev)``. The SimContext of a
-  sub-batch is a pure function of its ``_part_key`` (plus geometry and the
-  fleet-shared constants), so sweep grids that revisit a step structure —
+  sub-batch is a pure function of its ``_part_key``, the step engine its
+  lanes per device pick (plus geometry and the fleet-shared constants),
+  so a one-lane and a many-lane sub-batch never share a runner, and
+  sweep grids that revisit a step structure —
   e.g. the same policy grid at a new seed set, or bench scaling curves —
   reuse the jitted runner and pay XLA compilation once per structure.
   :func:`step_cache_stats` exposes hit/miss counters (asserted by
@@ -170,6 +179,14 @@ def step_cache_clear() -> None:
     _STATS.misses = 0
 
 
+def split_step(fast_path: bool | None, lanes: int) -> bool:
+    """The step engine of a sub-batch whose devices scan ``lanes`` drives
+    each: the split step (True) for one lane, which the runner scans
+    without vmap, the single-path step (False) for vmapped lanes.
+    ``fast_path`` True or False overrides the choice."""
+    return lanes == 1 if fast_path is None else fast_path
+
+
 def subbatch_runner(ctx: SimContext, n_total: int, on_device_sampler: bool,
                     n_dev: int):
     """Compiled runner for one sub-batch, memoized on its step structure.
@@ -217,10 +234,11 @@ def subbatch_runner(ctx: SimContext, n_total: int, on_device_sampler: bool,
         return st, trace, lbas
 
     def batched(*args):
-        # one lane per device: scan it unbatched. Under vmap every
-        # lax.cond of the step is a select that runs both branches (the GC
-        # drains, the §5.6 demotion replay) on every write; unbatched they
-        # are real branches taken only when needed. Same values either way.
+        # one lane per device: scan it unbatched, so every lax.cond of the
+        # step is a real branch taken only when needed; the same lane
+        # count gave ctx the split step (split_step). Under vmap a cond is
+        # a select that runs both branches on every write, and ctx holds
+        # the single-path step. Same values either way.
         if jax.tree_util.tree_leaves(args)[0].shape[0] == 1:
             out = run_one(*jax.tree_util.tree_map(lambda a: a[0], args))
             return jax.tree_util.tree_map(lambda a: a[None], out)

@@ -101,18 +101,19 @@ Architecture (post fast-path refactor — see also the bulk-GC notes below):
   selection and ``SimState.check_invariants`` (the debug checker that
   proves the counters never drift).
 
-* **Fast path / heavy path.** ``make_step`` splits the write into a lean
-  fast path — invalidate counters, pick the target group, append to the
-  group's open active block through the fused ``kernels/write_path`` op
-  (Pallas on TPU, flat gather/scatter lowering elsewhere) — and a heavy
-  path (GC, emergency valve, movement ops, §5.1 interval bookkeeping)
-  entered only when the scalar predicates demand it: the active block is
-  full, the pool is at reserve, a group holds redeemable surplus, or the
-  interval elapses. GC is a rare event amortized over many steady-state
-  writes (cf. Nagel et al., arXiv:1807.09313); under plain jit the heavy
-  machinery is a real untaken branch on most writes. The seed-shaped
-  single-path step survives as ``SimContext.fast_path=False`` and is the
-  step-equivalence oracle (tests/test_write_engine.py).
+* **Fast path / heavy path.** ``make_step`` splits the write: after the
+  invalidate and the target group, a heavy path (GC, emergency valve,
+  movement ops, §5.1 interval bookkeeping) is entered only when the
+  scalar predicates demand it — the active block is full, the pool is at
+  reserve, a group holds redeemable surplus, or the interval elapses —
+  and every other write takes the lean append to the group's open active
+  block (``kernels/write_path.apply_write``). GC is a rare event
+  amortized over many steady-state writes (cf. Nagel et al.,
+  arXiv:1807.09313); under plain jit the heavy machinery is a real
+  untaken branch on most writes. The seed-shaped single-path step
+  survives as ``SimContext.fast_path=False``: the step-equivalence oracle
+  (tests/test_write_engine.py), and the step of vmapped fleet lanes,
+  where a cond runs both branches (``fleet_exec.split_step``).
 
 * **Chunked scan + strided tracing.** :func:`scan_writes` scans chunks of
   ``trace_every`` writes (inner scan ``unroll``-ed) and emits the
@@ -635,11 +636,10 @@ def _invalidate_counts(ctx: SimContext, st: SimState, lba):
     """The counter half of :func:`_invalidate`: live/grp_size decrements and
     the old-group lookup, WITHOUT the valid-bit clear.
 
-    The fast-path step defers the clear into the fused ``write_path`` op
-    (heavy steps apply it via :func:`_clear_valid` before any GC runs).
-    Nothing between here and there reads ``valid`` — target selection only
-    touches group stats and the bloom pair — so the split is exact.
-    Returns (st, old_g, old_pm).
+    The split step and the TRIM step leave the clear to their fused
+    ``write_path`` op. Nothing between here and there reads ``valid`` —
+    target selection only touches group stats and the bloom pair — so the
+    split is exact. Returns (st, old_g, old_pm).
     """
     b = ctx.geom.pages_per_block
     pm = st.page_map[lba]
@@ -654,20 +654,6 @@ def _invalidate_counts(ctx: SimContext, st: SimState, lba):
         mapped_pages=st.mapped_pages + jnp.where(has, -1, 0),
     )
     return st, jnp.where(has, old_g, 0), pm
-
-
-@_scoped("ssd.append")
-def _clear_valid(ctx: SimContext, st: SimState, pm):
-    """Complete a deferred invalidate: clear the old slot's valid bit."""
-    b = ctx.geom.pages_per_block
-    has = pm >= 0
-    pm_c = jnp.maximum(pm, 0)
-    blk_c, slot = pm_c // b, pm_c % b
-    return st.replace(
-        valid=st.valid.at[blk_c, slot].set(
-            jnp.where(has, False, st.valid[blk_c, slot])
-        )
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -1798,12 +1784,21 @@ def make_step(ctx: SimContext, policy, rate_fn, page_group0=None):
     With ``ctx.fast_path=True`` (default) the write is split: one whose
     target group has an open active block with room, with the pool above
     reserve, no redeemable movement surplus anywhere, and no interval
-    boundary, takes the LEAN branch — invalidate counters, pick the group,
-    and one fused append (``kernels/write_path``). Everything else
-    (:func:`_step_tail`) runs only when one of those O(1) scalar predicates
-    trips. The predicates are exact, not conservative: a fast write is
-    bit-identical to what the heavy path would have produced, which
-    tests/test_write_engine.py asserts against ``fast_path=False``.
+    boundary, is LEAN — invalidate counters, pick the group, and one fused
+    invalidate-and-append (``kernels/write_path``). Everything else
+    (:func:`_step_tail`) runs only when one of those O(1) scalar
+    predicates trips. The predicates are exact, not conservative: a lean
+    write is bit-identical to what the heavy path would have produced,
+    which tests/test_write_engine.py asserts against ``fast_path=False``.
+    The split pays where the heavy tail is a real branch: under plain
+    jit, and in a fleet whose device scans one lane
+    (``fleet_exec.split_step`` picks it there); under vmap the tail's
+    cond is a select that runs on every write, so vmapped lanes take the
+    single path. The lean append runs before the tail's cond, masked, and
+    the cond's other branch passes the pools through: no branch rewrites
+    a pool the other passes through, so XLA updates ``page_map``,
+    ``slot_lba`` and ``valid`` in place on every lean write
+    (tests/test_write_engine.py reads the compiled step).
     """
     geom, mcfg = ctx.geom, ctx.mcfg
     b = geom.pages_per_block
@@ -1848,8 +1843,8 @@ def make_step(ctx: SimContext, policy, rate_fn, page_group0=None):
         #    _write_page alloc are both predicated on the block being full
         #    (low_pool alone never GCs without needs_block);
         #  * free_blocks ≥ 2 → the emergency valve cannot fire, and the
-        #    fast write claims no block so the pool is untouched;
-        #  * movement: a fast write changes no grp_phys/grp_alloc, so the
+        #    lean append claims no block so the pool is untouched;
+        #  * movement: a lean append changes no grp_phys/grp_alloc, so the
         #    post-write surplus the tail would read equals the carried
         #    pre-write surplus — if its max is < 1, movement cannot fire;
         #  * the interval predicate is the tail's own (op-stream mode
@@ -1871,36 +1866,38 @@ def make_step(ctx: SimContext, policy, rate_fn, page_group0=None):
             is_interval = ((t + 1) % h) == 0
         heavy = (~has_room) | valve_may | movement_may | is_interval
 
-        def heavy_path(st):
-            st = _clear_valid(ctx, st, old_pm)
-            return _step_tail(ctx, st, lba, t, g, policy, lookup)
-
+        # every write completes its invalidate here; a lean one also
+        # appends (a heavy one appends in the tail). The lean write runs
+        # before the cond rather than as its other branch, so the cond
+        # passes the pools through on a lean write and XLA updates them
+        # in place
         @_scoped("ssd.append")
-        def fast_path(st):
-            slot = st.fill[blk_c]
+        def lean_write(st):
+            lean = ~heavy
+            d = lean.astype(jnp.int32)
             page_map, slot_lba, valid = apply_write(
-                st.page_map, st.slot_lba, st.valid, lba, old_pm, blk_c, slot
+                st.page_map, st.slot_lba, st.valid, lba, old_pm, blk_c,
+                st.fill[blk_c], enabled=lean,
             )
             return st.replace(
                 page_map=page_map,
                 slot_lba=slot_lba,
                 valid=valid,
-                fill=st.fill.at[blk_c].add(1),
-                live=st.live.at[blk_c].add(1),
-                grp_size=st.grp_size.at[g].add(1),
-                grp_live=st.grp_live.at[g].add(1),
-                mapped_pages=st.mapped_pages + 1,
-                n_app=st.n_app + 1,
-                grp_writes=st.grp_writes.at[g].add(1),
+                fill=st.fill.at[blk_c].add(d),
+                live=st.live.at[blk_c].add(d),
+                grp_size=st.grp_size.at[g].add(d),
+                grp_live=st.grp_live.at[g].add(d),
+                mapped_pages=st.mapped_pages + d,
+                n_app=st.n_app + d,
+                grp_writes=st.grp_writes.at[g].add(d),
             )
 
-        out = jax.lax.cond(
+        return _cond_fields(
             heavy,
-            lambda s: _fields_of(heavy_path(s), _STEP_FIELDS),
-            lambda s: _fields_of(fast_path(s), _STEP_FIELDS),
-            st,
+            lambda s: _step_tail(ctx, s, lba, t, g, policy, lookup),
+            lean_write(st),
+            _STEP_FIELDS,
         )
-        return st.replace(**dict(zip(_STEP_FIELDS, out)))
 
     # degraded drives (faults only) freeze before any per-op work runs
     reference_write_g = _halt_wrap(ctx, reference_write)

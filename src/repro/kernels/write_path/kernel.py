@@ -12,8 +12,9 @@ slice outside the kernel: the map is a 1-D [LBA] pool whose length need
 not be a multiple of the 128-lane tile, so no aligned row DMA covers its
 tail.
 
-``ok`` masks the whole op (a disabled call must leave every pool untouched)
-and ``old_pm < 0`` masks just the invalidate.
+``old_pm < 0`` masks the invalidate and ``ok`` the append with its map
+entry: the simulator's split step invalidates on every write here, before
+its heavy-path cond, and appends only on the lean ones.
 
 ``apply_trim`` is the discard peer: the same shape with the append dropped
 — clear the old slot's valid bit, store -1 into the packed map. It backs
@@ -34,10 +35,10 @@ from repro.kernels.hbm_rows import ANY, drive_batched, rmw_row
 _W = 4  # scalar-prefetch words per drive: (lba, old_pm, new_pm, ok)
 
 
-def _clear_old(val_out, n, old, ok, buf, sem):
+def _clear_old(val_out, n, old, when, buf, sem):
     b = val_out.shape[2]
 
-    @pl.when(ok & (old >= 0))
+    @pl.when(when)
     def _clear():
         def update(row):
             row[old % b] = jnp.int32(0)
@@ -53,7 +54,7 @@ def _apply_write_kernel(ops_ref, lba_in, val_in, lba_out, val_out, buf, sem):
     old = ops_ref[_W * n + 1]
     new = ops_ref[_W * n + 2]
     ok = ops_ref[_W * n + 3] != 0
-    _clear_old(val_out, n, old, ok, buf, sem)
+    _clear_old(val_out, n, old, old >= 0, buf, sem)
 
     @pl.when(ok)
     def _set():
@@ -72,7 +73,7 @@ def _apply_trim_kernel(ops_ref, val_in, val_out, buf, sem):
     n = pl.program_id(0)
     old = ops_ref[_W * n + 1]
     ok = ops_ref[_W * n + 3] != 0
-    _clear_old(val_out, n, old, ok, buf, sem)
+    _clear_old(val_out, n, old, ok & (old >= 0), buf, sem)
 
 
 def _pool_call(kernel, pools, ops, interpret):

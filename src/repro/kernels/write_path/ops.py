@@ -1,4 +1,4 @@
-"""Public ops: apply_write / apply_trim (Pallas on TPU, flat lowering off-TPU)."""
+"""Public ops: apply_write / apply_trim (Pallas on TPU, XLA lowering off-TPU)."""
 
 from __future__ import annotations
 
@@ -9,31 +9,32 @@ from .kernel import apply_write as _apply_write_kernel
 from .ref import (
     apply_trim_flat,
     apply_trim_ref,
-    apply_write_flat,
     apply_write_ref,
+    apply_write_xla,
 )
 
 
-def apply_write(page_map, slot_lba, valid, lba, old_pm, dst_blk, dst_slot):
+def apply_write(page_map, slot_lba, valid, lba, old_pm, dst_blk, dst_slot,
+                enabled=True):
     """Fused fast-path write for core/simulator's split step: invalidate
-    the old physical slot of ``lba`` and append it at (dst_blk, dst_slot)
-    in one op over the three mapping pools.
+    the old physical slot of ``lba`` and, where ``enabled``, append it at
+    (dst_blk, dst_slot), in one op over the three mapping pools.
 
-    On TPU the (lba, old_pm, new_pm) row feeds the Pallas scalar-prefetch
-    kernel with the pools aliased in place. Off-TPU the flattened
-    single-element lowering runs instead (identical math — asserted equal
-    to the 2-D reference and the interpret-mode kernel in
-    tests/test_kernels.py): this op sits inside the per-write ``lax.scan``
-    of a possibly-vmapped fleet, where interpret-mode grid emulation would
-    serialize the very hot path the fast-path split exists to speed up.
+    On TPU the (lba, old_pm, new_pm, enabled) row feeds the Pallas
+    scalar-prefetch kernel with the pools aliased in place. Off-TPU the
+    in-place XLA lowering runs instead (identical math — asserted equal to
+    the 2-D reference and the interpret-mode kernel in
+    tests/test_kernels.py): this op sits inside the per-write ``lax.scan``,
+    where interpret-mode grid emulation would serialize the very hot path
+    the fast-path split exists to speed up.
     """
     if jax.default_backend() == "tpu":
         return _apply_write_kernel(
             page_map, slot_lba, valid, lba, old_pm, dst_blk, dst_slot,
-            interpret=False,
+            enabled=enabled, interpret=False,
         )
-    return apply_write_flat(
-        page_map, slot_lba, valid, lba, old_pm, dst_blk, dst_slot
+    return apply_write_xla(
+        page_map, slot_lba, valid, lba, old_pm, dst_blk, dst_slot, enabled
     )
 
 
@@ -51,6 +52,6 @@ def apply_trim(page_map, valid, lba, old_pm):
 
 
 __all__ = [
-    "apply_write", "apply_write_ref", "apply_write_flat",
+    "apply_write", "apply_write_ref", "apply_write_xla",
     "apply_trim", "apply_trim_ref", "apply_trim_flat",
 ]

@@ -3,11 +3,12 @@
 One application write on the simulator's fast path touches exactly three
 mapping structures: the old physical slot's valid bit (the invalidate), the
 destination slot's (lba, valid) pair (the append), and the packed
-logical→physical ``page_map`` entry. ``apply_write_ref`` is the obvious
-2-D-indexed formulation; ``apply_write_flat`` is the lowering the simulator
-uses off-TPU — every update is a single-element dynamic-update-slice on the
-FLATTENED pools, which XLA lowers natively (no scatter expansion, no
-capacity-sized masks) and which stays cheap under vmap.
+logical→physical ``page_map`` entry. The split step makes the call on every
+write, before its heavy-path cond: ``enabled`` masks the append (a write
+that takes the heavy path appends there), never the invalidate.
+``apply_write_ref`` is the obvious 2-D-indexed formulation;
+``apply_write_xla`` is the lowering the simulator uses off-TPU, shaped so
+that XLA updates the pools in place inside the step's scan.
 
 The TRIM peer (``apply_trim_ref`` / ``apply_trim_flat``) is the same op
 minus the append: kill the old slot's valid bit and unmap the page — the
@@ -28,8 +29,10 @@ def apply_write_ref(
     old_pm: jax.Array,    # [] int32 page's old packed address (-1 = none)
     dst_blk: jax.Array,   # [] int32 destination block (an OPEN active block)
     dst_slot: jax.Array,  # [] int32 destination slot (the block's fill ptr)
+    enabled: jax.Array | bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Invalidate ``old_pm``, land ``lba`` at ``(dst_blk, dst_slot)``.
+    """Invalidate ``old_pm``; where ``enabled``, land ``lba`` at
+    ``(dst_blk, dst_slot)`` and repoint its map entry.
 
     The destination is always a fresh slot strictly above the block's
     current fill pointer, so it can never equal the old slot — the clear
@@ -41,9 +44,12 @@ def apply_write_ref(
     ob, os = old_c // b, old_c % b
     valid = valid.at[ob, os].set(jnp.where(has_old, False, valid[ob, os]))
     new_pm = dst_blk * b + dst_slot
-    slot_lba = slot_lba.at[dst_blk, dst_slot].set(lba)
-    valid = valid.at[dst_blk, dst_slot].set(True)
-    page_map = page_map.at[lba].set(new_pm)
+    slot_lba = slot_lba.at[dst_blk, dst_slot].set(
+        jnp.where(enabled, lba, slot_lba[dst_blk, dst_slot]))
+    valid = valid.at[dst_blk, dst_slot].set(
+        jnp.where(enabled, True, valid[dst_blk, dst_slot]))
+    page_map = page_map.at[lba].set(
+        jnp.where(enabled, new_pm, page_map[lba]))
     return page_map, slot_lba, valid
 
 
@@ -76,8 +82,7 @@ def apply_trim_flat(
     old_pm: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
     """Flattened-index lowering of :func:`apply_trim_ref` (CPU/GPU path):
-    one dropped-out-of-bounds single-element store per pool, mirroring
-    :func:`apply_write_flat`."""
+    one dropped-out-of-bounds single-element store per pool."""
     kk, b = valid.shape
     old_c = jnp.where(old_pm >= 0, old_pm, kk * b)
     vflat = valid.reshape(-1).at[old_c].set(False, mode="drop")
@@ -85,7 +90,7 @@ def apply_trim_flat(
     return page_map, vflat.reshape(kk, b)
 
 
-def apply_write_flat(
+def apply_write_xla(
     page_map: jax.Array,
     slot_lba: jax.Array,
     valid: jax.Array,
@@ -93,19 +98,24 @@ def apply_write_flat(
     old_pm: jax.Array,
     dst_blk: jax.Array,
     dst_slot: jax.Array,
+    enabled: jax.Array | bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Flattened-index lowering of :func:`apply_write_ref` (CPU/GPU path).
+    """Off-TPU lowering of :func:`apply_write_ref`, in place under XLA.
 
-    ``old_pm`` IS the flat index of the old slot (the packed map stores
-    ``blk·B + slot``), so the invalidate needs no decode at all; a missing
-    old mapping is redirected out of bounds and dropped.
+    Both ``valid`` updates are one scatter whose masked-off rows point out
+    of bounds and are dropped: no update reads a ``valid`` element another
+    one writes, which would keep the pre-write pool alive and make XLA copy
+    it. ``slot_lba`` and ``page_map`` each read and write one element at the
+    same index.
     """
     kk, b = slot_lba.shape
-    old_c = jnp.where(old_pm >= 0, old_pm, kk * b)
-    new_pm = (dst_blk * b + dst_slot).astype(page_map.dtype)
-    vflat = valid.reshape(-1)
-    vflat = vflat.at[old_c].set(False, mode="drop")
-    vflat = vflat.at[new_pm].set(True)
-    lflat = slot_lba.reshape(-1).at[new_pm].set(lba)
-    page_map = page_map.at[lba].set(new_pm)
-    return page_map, lflat.reshape(kk, b), vflat.reshape(kk, b)
+    old_c = jnp.maximum(old_pm, 0)
+    rows = jnp.stack([jnp.where(old_pm >= 0, old_c // b, kk),
+                      jnp.where(enabled, dst_blk, kk)])
+    cols = jnp.stack([old_c % b, dst_slot])
+    valid = valid.at[rows, cols].set(jnp.array([False, True]), mode="drop")
+    slot_lba = slot_lba.at[dst_blk, dst_slot].set(
+        jnp.where(enabled, lba, slot_lba[dst_blk, dst_slot]))
+    page_map = page_map.at[lba].set(
+        jnp.where(enabled, dst_blk * b + dst_slot, page_map[lba]))
+    return page_map, slot_lba, valid

@@ -218,17 +218,18 @@ class TestCompactSlots:
 
 
 class TestWritePath:
-    """Fused fast-path write (invalidate + append + map repoint) backing the
-    simulator's split step: the flattened off-TPU lowering must match both
-    the 2-D reference and the interpret-mode Pallas kernel update-for-update."""
+    """Fused fast-path write (invalidate + masked append + map repoint)
+    backing the simulator's split step: the off-TPU XLA lowering must match
+    both the 2-D reference and the interpret-mode Pallas kernel
+    update-for-update."""
 
     @settings(max_examples=8, deadline=None)
     @given(st.integers(min_value=0, max_value=1000))
     def test_kernel_matches_ref(self, seed):
         from repro.kernels.write_path.kernel import apply_write
         from repro.kernels.write_path.ref import (
-            apply_write_flat,
             apply_write_ref,
+            apply_write_xla,
         )
 
         rng = np.random.default_rng(seed)
@@ -243,41 +244,47 @@ class TestWritePath:
         dst_slot = int(rng.integers(0, b))
         while dst_blk * b + dst_slot == old_pm:
             dst_slot = (dst_slot + 1) % b
+        enabled = jnp.asarray(bool(rng.random() < 0.75))
         args = (
             jnp.asarray(page_map), jnp.asarray(slot_lba), jnp.asarray(valid),
             jnp.asarray(lba), jnp.asarray(old_pm),
             jnp.asarray(dst_blk), jnp.asarray(dst_slot),
         )
-        ref_pm, ref_l, ref_v = apply_write_ref(*args)
-        flat_pm, flat_l, flat_v = apply_write_flat(*args)
-        ker_pm, ker_l, ker_v = apply_write(*args, interpret=True)
+        ref_pm, ref_l, ref_v = apply_write_ref(*args, enabled)
+        xla_pm, xla_l, xla_v = apply_write_xla(*args, enabled)
+        ker_pm, ker_l, ker_v = apply_write(
+            *args, enabled=enabled, interpret=True)
         for got, ref, name in (
-            (flat_pm, ref_pm, "flat page_map"), (flat_l, ref_l, "flat slot_lba"),
-            (flat_v, ref_v, "flat valid"),
+            (xla_pm, ref_pm, "xla page_map"), (xla_l, ref_l, "xla slot_lba"),
+            (xla_v, ref_v, "xla valid"),
             (ker_pm, ref_pm, "kernel page_map"), (ker_l, ref_l, "kernel slot_lba"),
             (ker_v, ref_v, "kernel valid"),
         ):
             np.testing.assert_array_equal(
                 np.asarray(got), np.asarray(ref), err_msg=name
             )
-        assert flat_v.dtype == valid.dtype and ker_v.dtype == valid.dtype
-        # the new mapping is installed and the old slot is dead
-        assert int(flat_pm[lba]) == dst_blk * b + dst_slot
-        assert bool(flat_v[dst_blk, dst_slot])
+        assert xla_v.dtype == valid.dtype and ker_v.dtype == valid.dtype
+        # the old slot is dead either way; the new mapping is installed
+        # only where enabled
         if old_pm >= 0:
-            assert not bool(flat_v[old_pm // b, old_pm % b])
+            assert not bool(ref_v[old_pm // b, old_pm % b])
+        if bool(enabled):
+            assert int(ref_pm[lba]) == dst_blk * b + dst_slot
+            assert bool(ref_v[dst_blk, dst_slot])
+        else:
+            assert int(ref_pm[lba]) == old_pm
 
     def test_unmapped_page_touches_nothing_old(self):
         from repro.kernels.write_path.ref import (
-            apply_write_flat,
             apply_write_ref,
+            apply_write_xla,
         )
 
         k, b, lba_pages = 8, 4, 24
         page_map = jnp.full(lba_pages, -1, jnp.int32)
         slot_lba = jnp.full((k, b), -1, jnp.int32)
         valid = jnp.zeros((k, b), bool)
-        for fn in (apply_write_ref, apply_write_flat):
+        for fn in (apply_write_ref, apply_write_xla):
             pm, sl, va = fn(
                 page_map, slot_lba, valid,
                 jnp.asarray(5), jnp.asarray(-1),
@@ -288,26 +295,36 @@ class TestWritePath:
             assert int(sl[2, 0]) == 5
 
     def test_disabled_kernel_write_is_noop(self):
+        """A disabled write appends nothing: with no old mapping it leaves
+        every pool untouched, and with one it only kills the old slot."""
         from repro.kernels.write_path.kernel import apply_write
+        from repro.kernels.write_path.ref import apply_write_xla
 
         rng = np.random.default_rng(0)
         k, b, lba_pages = 8, 4, 24
         page_map = jnp.asarray(rng.integers(-1, k * b, lba_pages), jnp.int32)
         slot_lba = jnp.asarray(rng.integers(-1, lba_pages, (k, b)), jnp.int32)
         valid = jnp.asarray(rng.random((k, b)) < 0.5)
-        pm, sl, va = apply_write(
-            page_map, slot_lba, valid,
-            jnp.asarray(3), jnp.asarray(5), jnp.asarray(1), jnp.asarray(2),
-            enabled=jnp.asarray(False), interpret=True,
-        )
-        np.testing.assert_array_equal(np.asarray(pm), np.asarray(page_map))
-        np.testing.assert_array_equal(np.asarray(sl), np.asarray(slot_lba))
-        np.testing.assert_array_equal(np.asarray(va), np.asarray(valid))
+        for old in (-1, 5):
+            args = (page_map, slot_lba, valid, jnp.asarray(3),
+                    jnp.asarray(old), jnp.asarray(1), jnp.asarray(2))
+            want_v = valid if old < 0 else valid.at[old // b, old % b].set(
+                False)
+            for pm, sl, va in (
+                apply_write(*args, enabled=jnp.asarray(False), interpret=True),
+                apply_write_xla(*args, jnp.asarray(False)),
+            ):
+                np.testing.assert_array_equal(np.asarray(pm),
+                                              np.asarray(page_map))
+                np.testing.assert_array_equal(np.asarray(sl),
+                                              np.asarray(slot_lba))
+                np.testing.assert_array_equal(np.asarray(va),
+                                              np.asarray(want_v))
 
 
 class TestTrimPath:
-    """Fused fast-path TRIM (invalidate + unmap) — the discard peer of
-    apply_write: flat lowering and interpret-mode kernel vs the 2-D ref."""
+    """Fused fast-path TRIM (invalidate + unmap): flat lowering and
+    interpret-mode kernel vs the 2-D ref."""
 
     @settings(max_examples=8, deadline=None)
     @given(st.integers(min_value=0, max_value=1000))

@@ -9,20 +9,25 @@ manager presets, under both jit (``managers.simulate``) and vmap
 (``simulate_fleet``), and against the ``gc_impl="reference"`` oracle so the
 whole new engine is anchored to the seed semantics end-to-end.
 
-Also here: the strided-trace contract (``trace_every=k`` samples the dense
-cumulative counters exactly) and the O(1)-accounting invariant property
-test (``SimState.check_invariants`` after random write segments under both
-GC drains).
+Also here: the fleet's choice of engine by lanes per device (one lane →
+split, vmapped lanes → single path) and the in-place lean write of the
+compiled one-lane runner, the strided-trace contract (``trace_every=k``
+samples the dense cumulative counters exactly) and the O(1)-accounting
+invariant property test (``SimState.check_invariants`` after random write
+segments under both GC drains).
 """
 
 import dataclasses
 import inspect
+import re
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.core import fleet, fleet_exec
 from repro.core import managers as M
 from repro.core import simulator as S
 from repro.core import workloads as W
@@ -116,6 +121,140 @@ class TestStepEquivalence:
         np.testing.assert_array_equal(
             split.wa_curves(1000), oracle.wa_curves(1000)
         )
+
+
+# (specs, the engine simulate_fleet's default picks for their one
+# sub-batch): a lone drive is one lane on its device, two drives on one
+# device are vmapped lanes
+_LBA = GEOM.lba_pages
+_ENGINE_CASES = {
+    "wolf_tpcc_one": (
+        [DriveSpec(M.wolf(), (W.tpcc_like(_LBA, N_WRITES),), seed=21)],
+        "split"),
+    "wolfdyn_swap_one": (
+        [DriveSpec(M.wolf_dynamic(), tuple(W.swap_phases(_LBA, N_WRITES // 2)),
+                   seed=22)],
+        "split"),
+    "two_lanes": (
+        [DriveSpec(M.wolf(), (W.tpcc_like(_LBA, N_WRITES),), seed=23),
+         DriveSpec(M.wolf(), (W.two_modal(_LBA, N_WRITES),), seed=24)],
+        "single"),
+}
+
+
+def _runner_hlo(spec, monkeypatch) -> str:
+    """Optimized HLO of the runner a one-drive fleet of ``spec`` calls."""
+    got = {}
+
+    class Captured(Exception):
+        pass
+
+    def spy(*key):
+        def run(*args):
+            got["fn"] = fleet_exec.subbatch_runner(*key)
+            got["shapes"] = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+            raise Captured
+
+        return run
+
+    monkeypatch.setattr(fleet, "subbatch_runner", spy)
+    with pytest.raises(Captured):
+        simulate_fleet(GEOM, [spec], sampler="jax", trace_every=500)
+    return got["fn"].lower(*got["shapes"]).compile().as_text()
+
+
+def _computations(hlo: str) -> dict:
+    """{computation name: its instruction lines}, and "ENTRY" for the entry."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = "ENTRY" if m.group(1) else m.group(2)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    return comps
+
+
+class TestFleetEngine:
+    """simulate_fleet's default engine follows the lanes per device: a
+    device scanning one drive takes the split step, vmapped lanes the
+    single path, and both equal the seed-shaped oracle."""
+
+    @pytest.mark.parametrize("case", sorted(_ENGINE_CASES))
+    def test_default_engine_matches_oracle(self, case):
+        specs, engine = _ENGINE_CASES[case]
+        got = simulate_fleet(GEOM, specs, sampler="jax")
+        oracle = simulate_fleet(
+            GEOM, specs, sampler="jax", fast_path=False, gc_impl="reference",
+        )
+        assert [m["engine"] for m in got.exec_meta] == [engine]
+        assert [m["engine"] for m in oracle.exec_meta] == ["single"]
+        np.testing.assert_array_equal(got.app, oracle.app)
+        np.testing.assert_array_equal(got.mig, oracle.mig)
+        for i, s in enumerate(specs):
+            assert int(got.state(i)["n_dropped"]) == 0
+            for key, arr in got.state(i).items():
+                np.testing.assert_array_equal(
+                    np.asarray(arr), np.asarray(oracle.state(i)[key]),
+                    err_msg=f"{s.label}: state[{key}]",
+                )
+
+    def test_runner_memo_keys_on_engine(self, monkeypatch):
+        """A one-lane sub-batch never gets the runner a many-lane
+        sub-batch of the same step structure compiled, nor the reverse."""
+        monkeypatch.setattr(fleet_exec, "_RUNNERS", {})
+        monkeypatch.setattr(fleet_exec, "_STATS", fleet_exec.CacheStats())
+        real, built = fleet.subbatch_runner, []
+
+        def spy(ctx, *rest):
+            fn = real(ctx, *rest)
+            built.append((ctx.fast_path, fn))
+            return fn
+
+        monkeypatch.setattr(fleet, "subbatch_runner", spy)
+        spec = DriveSpec(M.wolf(), (W.tpcc_like(_LBA, 600),), seed=1)
+        for specs in ([spec], [spec, spec], [spec]):
+            simulate_fleet(GEOM, specs)
+        (one, f_one), (many, f_many), (again, f_again) = built
+        assert (one, many, again) == (True, False, True)
+        assert f_one is not f_many and f_again is f_one
+        stats = fleet_exec.step_cache_stats()
+        assert (stats.misses, stats.hits) == (2, 1)
+
+    def test_lean_write_updates_pools_in_place(self, monkeypatch):
+        """In the compiled one-lane runner, no loop body that runs on every
+        write, and no pass-through branch of a cond in one, copies an array
+        of the page_map, slot_lba or valid shape: the lean write updates
+        the pools in place (copies stay in the heavy tail)."""
+        k, b = GEOM.n_blocks, GEOM.pages_per_block
+        pool = re.compile(
+            rf"= (s32|pred)\[({GEOM.lba_pages}|{k},{b}|{k * b})\]"
+            r"\S* copy\(")
+        spec = DriveSpec(M.wolf_dynamic(), (W.tpcc_like(_LBA, 1000),), seed=3)
+        comps = _computations(_runner_hlo(spec, monkeypatch))
+
+        def callees(name, pattern):
+            return [c for line in comps[name]
+                    for m in re.finditer(pattern, line)
+                    for c in m.group(1).replace("%", "").split(", ")]
+
+        loops, todo = [], ["ENTRY"]
+        while todo:  # the entry and the loop bodies nested in it
+            name = todo.pop()
+            loops.append(name)
+            todo += callees(name, r" while\(.*?body=%?([\w.\-]+)")
+        conds = [callees(name, r"branch_computations=\{([^}]*)\}")
+                 for name in loops]
+        assert len(loops) >= 3 and any(conds), loops
+        for name in loops:
+            assert not any(pool.search(line) for line in comps[name]), name
+        for branches in filter(None, conds):
+            thin = min(branches, key=lambda c: len(comps[c]))
+            assert not any(pool.search(line) for line in comps[thin]), thin
 
 
 @pytest.mark.trim
@@ -378,11 +517,12 @@ class TestEngineStructure:
         assert "bloom_rotate_min_writes" in src
 
     def test_fast_path_has_no_gc_machinery(self):
-        """The lean branch carries no GC/valve/interval calls."""
+        """The lean append carries no GC/valve/interval calls."""
         src = inspect.getsource(S.make_step)
-        after_def = src.split("def fast_path(st):")[1]
-        delim = "out = jax.lax.cond"
-        assert delim in after_def, "split_step cond structure changed"
+        after_def = src.split("def lean_write(st):")[1]
+        delim = "return _cond_fields("
+        assert delim in after_def, "split_write structure changed"
         fast = after_def.split(delim)[0]
-        for marker in ("_gc_one", "while_loop", "_interval_update"):
+        for marker in ("_gc_one", "while_loop", "_interval_update",
+                       "_step_tail"):
             assert marker not in fast, marker
