@@ -19,9 +19,7 @@ segments under both GC drains).
 
 import dataclasses
 import inspect
-import re
 
-import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -33,6 +31,8 @@ from repro.core import simulator as S
 from repro.core import workloads as W
 from repro.core.fleet import DriveSpec, simulate_fleet
 from repro.core.ssd import Geometry, ManagerConfig, assert_invariants
+
+from _runner_hlo import captured_runner, per_write_pool_copies
 
 GEOM = Geometry(n_luns=4, blocks_per_lun=32, pages_per_block=8, lba_pba=0.7)
 N_WRITES = 6_000
@@ -142,43 +142,6 @@ _ENGINE_CASES = {
 }
 
 
-def _runner_hlo(spec, monkeypatch) -> str:
-    """Optimized HLO of the runner a one-drive fleet of ``spec`` calls."""
-    got = {}
-
-    class Captured(Exception):
-        pass
-
-    def spy(*key):
-        def run(*args):
-            got["fn"] = fleet_exec.subbatch_runner(*key)
-            got["shapes"] = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-            raise Captured
-
-        return run
-
-    monkeypatch.setattr(fleet, "subbatch_runner", spy)
-    with pytest.raises(Captured):
-        simulate_fleet(GEOM, [spec], sampler="jax", trace_every=500)
-    return got["fn"].lower(*got["shapes"]).compile().as_text()
-
-
-def _computations(hlo: str) -> dict:
-    """{computation name: its instruction lines}, and "ENTRY" for the entry."""
-    comps, name = {}, None
-    for line in hlo.splitlines():
-        m = re.match(r"^(ENTRY )?%([\w.\-]+) \(.*\{$", line)
-        if m:
-            name = "ENTRY" if m.group(1) else m.group(2)
-            comps[name] = []
-        elif line.startswith("}"):
-            name = None
-        elif name:
-            comps[name].append(line)
-    return comps
-
-
 class TestFleetEngine:
     """simulate_fleet's default engine follows the lanes per device: a
     device scanning one drive takes the split step, vmapped lanes the
@@ -230,31 +193,10 @@ class TestFleetEngine:
         write, and no pass-through branch of a cond in one, copies an array
         of the page_map, slot_lba or valid shape: the lean write updates
         the pools in place (copies stay in the heavy tail)."""
-        k, b = GEOM.n_blocks, GEOM.pages_per_block
-        pool = re.compile(
-            rf"= (s32|pred)\[({GEOM.lba_pages}|{k},{b}|{k * b})\]"
-            r"\S* copy\(")
         spec = DriveSpec(M.wolf_dynamic(), (W.tpcc_like(_LBA, 1000),), seed=3)
-        comps = _computations(_runner_hlo(spec, monkeypatch))
-
-        def callees(name, pattern):
-            return [c for line in comps[name]
-                    for m in re.finditer(pattern, line)
-                    for c in m.group(1).replace("%", "").split(", ")]
-
-        loops, todo = [], ["ENTRY"]
-        while todo:  # the entry and the loop bodies nested in it
-            name = todo.pop()
-            loops.append(name)
-            todo += callees(name, r" while\(.*?body=%?([\w.\-]+)")
-        conds = [callees(name, r"branch_computations=\{([^}]*)\}")
-                 for name in loops]
-        assert len(loops) >= 3 and any(conds), loops
-        for name in loops:
-            assert not any(pool.search(line) for line in comps[name]), name
-        for branches in filter(None, conds):
-            thin = min(branches, key=lambda c: len(comps[c]))
-            assert not any(pool.search(line) for line in comps[thin]), thin
+        fn, shapes = captured_runner(GEOM, spec, monkeypatch, trace_every=500)
+        hlo = fn.lower(*shapes).compile().as_text()
+        assert per_write_pool_copies(hlo, GEOM) == []
 
 
 @pytest.mark.trim
