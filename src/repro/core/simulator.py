@@ -813,7 +813,7 @@ def _gc_drain_bulk(ctx: SimContext, st: SimState, victim, g, policy, rate_fn):
     g32 = jnp.asarray(g, jnp.int32)
 
     lbas = st.slot_lba[victim]            # [B]; dead slots hold -1
-    is_live = st.valid[victim]            # [B]
+    is_live = st.valid[victim] != 0       # [B]
     lbas_c = jnp.maximum(lbas, 0)
     n_live = jnp.sum(is_live)
 
@@ -1052,7 +1052,7 @@ def _gc_drain_bulk_static(ctx: SimContext, st: SimState, victim, g):
     lba_pages = st.page_map.shape[0]
 
     lbas = st.slot_lba[victim]            # [B]; dead slots hold -1
-    is_live = st.valid[victim]            # [B]
+    is_live = st.valid[victim] != 0       # [B]
     lbas_c = jnp.maximum(lbas, 0)
     n_live = jnp.sum(is_live)
     live_i = is_live.astype(jnp.int32)
@@ -1156,7 +1156,7 @@ def _gc_drain_reference(ctx: SimContext, st: SimState, victim, g, demote_fn):
         # masked migration (no lax.cond: under vmap a per-slot cond would
         # select over the whole state pytree B×/GC)
         lba = st.slot_lba[victim, j]
-        is_live = st.valid[victim, j]
+        is_live = st.valid[victim, j] != 0
         lba_c = jnp.maximum(lba, 0)  # dead slots hold -1
         st = st.replace(
             valid=st.valid.at[victim, j].set(
@@ -1970,11 +1970,7 @@ def scan_writes(ctx: SimContext, step, st: SimState, lbas, ts, ops=None):
     t_total = int(lbas.shape[0])
     e = ctx.trace_every
     cols = (lbas, ts) if ops is None else (ops, lbas, ts)
-    if e <= 1:
-        return jax.lax.scan(
-            step, st, cols, unroll=min(ctx.unroll, max(t_total, 1))
-        )
-    assert t_total % e == 0, (
+    assert e <= 1 or t_total % e == 0, (
         f"trace_every={e} must divide the segment length {t_total}"
     )
 
@@ -1989,8 +1985,18 @@ def scan_writes(ctx: SimContext, step, st: SimState, lbas, ts, ops=None):
             s, _ = jax.lax.scan(inner, s, xs, unroll=min(ctx.unroll, e))
         return s, (s.n_app, s.n_mig)
 
-    xs = tuple(c.reshape(t_total // e, e) for c in cols)
-    return jax.lax.scan(chunk, st, xs)
+    # the carry holds ``valid`` as int32 0/1, the dtype the pool kernels
+    # read and write in place (a bool row cannot be DMA'd): one conversion
+    # each way per scan, none per write
+    st = st.replace(valid=st.valid.astype(jnp.int32))
+    if e <= 1:
+        st, trace = jax.lax.scan(
+            step, st, cols, unroll=min(ctx.unroll, max(t_total, 1))
+        )
+    else:
+        xs = tuple(c.reshape(t_total // e, e) for c in cols)
+        st, trace = jax.lax.scan(chunk, st, xs)
+    return st.replace(valid=st.valid != 0), trace
 
 
 # st is DONATED: the scan's carry rewrites every state array, so aliasing

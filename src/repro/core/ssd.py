@@ -235,7 +235,7 @@ class SimState:
 
     page_map: jax.Array  # [LBA] int32 packed physical address, -1 unmapped
     slot_lba: jax.Array  # [K, B] int32 lba living in each slot, -1 empty
-    valid: jax.Array     # [K, B] bool
+    valid: jax.Array     # [K, B] bool (int32 0/1 in scan_writes' carry)
     live: jax.Array      # [K] int32 live pages per block
     fill: jax.Array      # [K] int32 written slots per block
     stamp: jax.Array     # [K] int32 LRU age (claim-time clock)

@@ -199,7 +199,9 @@ def compact_slots(
     bulk-GC drain: the pools are the [K, B] per-slot lba map and its valid
     bitmap, kept in HBM; the move list (a victim's live slots) is a
     scalar-prefetch operand and only the rows it names are touched. All
-    reads happen before any write, as in ``compact_slots_ref``."""
+    reads happen before any write, as in ``compact_slots_ref``. ``valid``
+    is int32 0/1 in the scan carry, so the casts around the call are
+    no-ops there; a bool pool is converted each way."""
     moves = jnp.stack(
         [src_block, src_slot, dst_block, dst_slot], axis=1
     ).astype(jnp.int32)

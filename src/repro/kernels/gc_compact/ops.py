@@ -26,6 +26,9 @@ def compact_slots(slot_lba, valid, src_block, src_slot, dst_block, dst_slot):
     of a possibly-vmapped fleet, where interpret-mode grid emulation or an
     XLA:CPU-expanded scatter loop would serialize the very hot path the
     bulk drain exists to speed up.
+
+    ``valid`` is int32 0/1 in the simulator's scan carry, the dtype the
+    kernel updates in place; a bool pool is accepted and comes back bool.
     """
     if jax.default_backend() == "tpu":
         return _compact_slots_kernel(

@@ -36,7 +36,7 @@ def gc_compact_ref(
 
 def compact_slots_ref(
     slot_lba: jax.Array,  # [K, B] int32 per-slot content (lba or -1)
-    valid: jax.Array,     # [K, B] bool per-slot liveness
+    valid: jax.Array,     # [K, B] per-slot liveness, int32 0/1 or bool
     src_block: jax.Array,  # [M] int32 source block per move (-1 = skip)
     src_slot: jax.Array,   # [M] int32
     dst_block: jax.Array,  # [M] int32 destination block

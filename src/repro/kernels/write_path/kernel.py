@@ -125,7 +125,7 @@ def _ops_row(lba, old_pm, new_pm, enabled):
 def apply_write(
     page_map: jax.Array,  # [LBA] int32
     slot_lba: jax.Array,  # [K, B] int32
-    valid: jax.Array,     # [K, B] bool
+    valid: jax.Array,     # [K, B] int32 0/1 (the scan carry's), or bool
     lba: jax.Array,       # [] int32
     old_pm: jax.Array,    # [] int32, -1 = page had no mapping
     dst_blk: jax.Array,   # [] int32
@@ -149,7 +149,7 @@ def apply_write(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def apply_trim(
     page_map: jax.Array,  # [LBA] int32
-    valid: jax.Array,     # [K, B] bool
+    valid: jax.Array,     # [K, B] int32 0/1 (the scan carry's), or bool
     lba: jax.Array,       # [] int32
     old_pm: jax.Array,    # [] int32, -1 = page had no mapping (no-op trim)
     *,

@@ -27,6 +27,10 @@ def apply_write(page_map, slot_lba, valid, lba, old_pm, dst_blk, dst_slot,
     tests/test_kernels.py): this op sits inside the per-write ``lax.scan``,
     where interpret-mode grid emulation would serialize the very hot path
     the fast-path split exists to speed up.
+
+    ``valid`` is int32 0/1 in the simulator's scan carry, the dtype the
+    kernel updates in place; a bool pool is accepted too (the kernel then
+    converts the whole pool each way) and comes back bool.
     """
     if jax.default_backend() == "tpu":
         return _apply_write_kernel(
@@ -43,7 +47,8 @@ def apply_trim(page_map, valid, lba, old_pm):
     — the discard peer of :func:`apply_write` (same dispatch rule, same
     equivalence suite). ``old_pm < 0`` (a re-trim of an already-unmapped
     page) leaves the valid pool untouched; the map entry is stored -1
-    unconditionally (it already held -1).
+    unconditionally (it already held -1). ``valid`` as in
+    :func:`apply_write`: int32 in the scan carry, bool accepted.
     """
     if jax.default_backend() == "tpu":
         return _apply_trim_kernel(page_map, valid, lba, old_pm,

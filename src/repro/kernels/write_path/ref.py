@@ -13,6 +13,9 @@ that XLA updates the pools in place inside the step's scan.
 The TRIM peer (``apply_trim_ref`` / ``apply_trim_flat``) is the same op
 minus the append: kill the old slot's valid bit and unmap the page — the
 fast path of the op-stream engine's discard handling.
+
+Every function here takes ``valid`` as bool or as int32 0/1 (the
+simulator's scan carry) and returns it in the dtype it came in.
 """
 
 from __future__ import annotations
@@ -24,7 +27,7 @@ import jax.numpy as jnp
 def apply_write_ref(
     page_map: jax.Array,  # [LBA] int32 packed physical address, -1 unmapped
     slot_lba: jax.Array,  # [K, B] int32 per-slot content (lba or -1)
-    valid: jax.Array,     # [K, B] bool per-slot liveness
+    valid: jax.Array,     # [K, B] per-slot liveness, int32 0/1 or bool
     lba: jax.Array,       # [] int32 page being written
     old_pm: jax.Array,    # [] int32 page's old packed address (-1 = none)
     dst_blk: jax.Array,   # [] int32 destination block (an OPEN active block)
@@ -55,7 +58,7 @@ def apply_write_ref(
 
 def apply_trim_ref(
     page_map: jax.Array,  # [LBA] int32 packed physical address, -1 unmapped
-    valid: jax.Array,     # [K, B] bool per-slot liveness
+    valid: jax.Array,     # [K, B] per-slot liveness, int32 0/1 or bool
     lba: jax.Array,       # [] int32 page being trimmed
     old_pm: jax.Array,    # [] int32 page's old packed address (-1 = none)
 ) -> tuple[jax.Array, jax.Array]:

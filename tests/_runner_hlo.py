@@ -52,6 +52,37 @@ def computations(hlo: str) -> dict:
     return comps
 
 
+def _callees(lines, pattern):
+    return [c for line in lines
+            for m in re.finditer(pattern, line)
+            for c in m.group(1).replace("%", "").split(", ")]
+
+
+def per_write_pool_converts(hlo: str, geom) -> list:
+    """Computations that a loop body of a compiled runner reaches (the body,
+    its fusions, conds and nested loops) and that convert a whole
+    valid-shaped pool: a ``convert`` to a [K, B] array (bool to int32) or a
+    ``compare`` that yields a [K, B] bool (int32 back to bool). Empty when
+    ``valid`` stays in one dtype across the per-write path."""
+    k, b = geom.n_blocks, geom.pages_per_block
+    whole = re.compile(rf"= (s32\[(1,)?{k},{b}\]\S* convert"
+                       rf"|pred\[(1,)?{k},{b}\]\S* compare)\(")
+    comps = computations(hlo)
+    seen = set()
+    todo = _callees(comps["ENTRY"], r" while\(.*?body=%?([\w.\-]+)")
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        todo += _callees(comps[name], r"(?:calls|to_apply|body|condition)"
+                                      r"=%?([\w.\-]+)")
+        todo += _callees(comps[name], r"branch_computations=\{([^}]*)\}")
+    assert len(seen) > 3, seen
+    return sorted(name for name in seen
+                  if any(whole.search(line) for line in comps[name]))
+
+
 def per_write_pool_copies(hlo: str, geom) -> list:
     """Computations on the per-write path of a compiled one-lane runner
     that copy an array of the page_map, slot_lba or valid shape: the entry
@@ -63,18 +94,12 @@ def per_write_pool_copies(hlo: str, geom) -> list:
         rf"= (s32|pred)\[({geom.lba_pages}|{k},{b}|{k * b})\]"
         r"\S* copy\(")
     comps = computations(hlo)
-
-    def callees(name, pattern):
-        return [c for line in comps[name]
-                for m in re.finditer(pattern, line)
-                for c in m.group(1).replace("%", "").split(", ")]
-
     loops, todo = [], ["ENTRY"]
     while todo:  # the entry and the loop bodies nested in it
         name = todo.pop()
         loops.append(name)
-        todo += callees(name, r" while\(.*?body=%?([\w.\-]+)")
-    conds = [callees(name, r"branch_computations=\{([^}]*)\}")
+        todo += _callees(comps[name], r" while\(.*?body=%?([\w.\-]+)")
+    conds = [_callees(comps[name], r"branch_computations=\{([^}]*)\}")
              for name in loops]
     assert len(loops) >= 3 and any(conds), loops
     thin = [min(branches, key=lambda c: len(comps[c]))

@@ -33,6 +33,12 @@ def _mk(key, shape, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * 0.5).astype(dtype)
 
 
+# the simulator's scan carries ``valid`` as int32 0/1; bool is accepted too
+VALID_DTYPES = pytest.mark.parametrize(
+    "valid_dtype", [np.bool_, np.int32], ids=["bool", "int32"]
+)
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize(
@@ -176,9 +182,10 @@ class TestCompactSlots:
     pure-jnp fallback the simulator runs off-TPU must match the
     interpret-mode Pallas kernel move-for-move."""
 
+    @VALID_DTYPES
     @settings(max_examples=8, deadline=None)
-    @given(st.integers(min_value=0, max_value=1000))
-    def test_kernel_matches_ref(self, seed):
+    @given(seed=st.integers(min_value=0, max_value=1000))
+    def test_kernel_matches_ref(self, valid_dtype, seed):
         from repro.kernels.gc_compact.kernel import compact_slots
         from repro.kernels.gc_compact.ref import (
             compact_slots_dense,
@@ -189,7 +196,7 @@ class TestCompactSlots:
         k, b = 24, 8
         m = int(rng.integers(1, b + 1))
         slot_lba = rng.integers(-1, 200, (k, b)).astype(np.int32)
-        valid = rng.random((k, b)) < 0.5
+        valid = (rng.random((k, b)) < 0.5).astype(valid_dtype)
         # a GC-shaped move list: one victim block's slots → distinct dsts
         victim = int(rng.integers(0, k))
         dst_flat = rng.choice((k - 1) * b, m, replace=False)
@@ -203,18 +210,21 @@ class TestCompactSlots:
         got_l, got_v = compact_slots(
             jnp.asarray(slot_lba), jnp.asarray(valid), *args, interpret=True
         )
+        # the reference on the bool pool: an int32 one moves the same bits
         ref_l, ref_v = compact_slots_ref(
-            jnp.asarray(slot_lba), jnp.asarray(valid), *args
+            jnp.asarray(slot_lba), jnp.asarray(valid.astype(bool)), *args
         )
+        ref_v = np.asarray(ref_v).astype(valid_dtype)
         np.testing.assert_array_equal(np.asarray(got_l), np.asarray(ref_l))
-        np.testing.assert_array_equal(np.asarray(got_v), np.asarray(ref_v))
+        np.testing.assert_array_equal(np.asarray(got_v), ref_v)
         assert got_v.dtype == valid.dtype
         # the scatter-free CPU lowering the simulator actually runs
         den_l, den_v = compact_slots_dense(
             jnp.asarray(slot_lba), jnp.asarray(valid), *args
         )
         np.testing.assert_array_equal(np.asarray(den_l), np.asarray(ref_l))
-        np.testing.assert_array_equal(np.asarray(den_v), np.asarray(ref_v))
+        np.testing.assert_array_equal(np.asarray(den_v), ref_v)
+        assert den_v.dtype == valid.dtype
 
 
 class TestWritePath:
@@ -223,9 +233,10 @@ class TestWritePath:
     both the 2-D reference and the interpret-mode Pallas kernel
     update-for-update."""
 
+    @VALID_DTYPES
     @settings(max_examples=8, deadline=None)
-    @given(st.integers(min_value=0, max_value=1000))
-    def test_kernel_matches_ref(self, seed):
+    @given(seed=st.integers(min_value=0, max_value=1000))
+    def test_kernel_matches_ref(self, valid_dtype, seed):
         from repro.kernels.write_path.kernel import apply_write
         from repro.kernels.write_path.ref import (
             apply_write_ref,
@@ -235,7 +246,7 @@ class TestWritePath:
         rng = np.random.default_rng(seed)
         k, b, lba_pages = 24, 8, 128
         slot_lba = rng.integers(-1, lba_pages, (k, b)).astype(np.int32)
-        valid = rng.random((k, b)) < 0.5
+        valid = (rng.random((k, b)) < 0.5).astype(valid_dtype)
         page_map = rng.integers(-1, k * b, lba_pages).astype(np.int32)
         lba = int(rng.integers(0, lba_pages))
         old_pm = int(page_map[lba])
@@ -250,7 +261,11 @@ class TestWritePath:
             jnp.asarray(lba), jnp.asarray(old_pm),
             jnp.asarray(dst_blk), jnp.asarray(dst_slot),
         )
-        ref_pm, ref_l, ref_v = apply_write_ref(*args, enabled)
+        # the reference on the bool pool: an int32 one ends with the same
+        # bits, as int32 0/1
+        ref_pm, ref_l, ref_v = apply_write_ref(
+            *args[:2], jnp.asarray(valid.astype(bool)), *args[3:], enabled)
+        ref_v = np.asarray(ref_v).astype(valid_dtype)
         xla_pm, xla_l, xla_v = apply_write_xla(*args, enabled)
         ker_pm, ker_l, ker_v = apply_write(
             *args, enabled=enabled, interpret=True)
@@ -326,9 +341,10 @@ class TestTrimPath:
     """Fused fast-path TRIM (invalidate + unmap): flat lowering and
     interpret-mode kernel vs the 2-D ref."""
 
+    @VALID_DTYPES
     @settings(max_examples=8, deadline=None)
-    @given(st.integers(min_value=0, max_value=1000))
-    def test_kernel_matches_ref(self, seed):
+    @given(seed=st.integers(min_value=0, max_value=1000))
+    def test_kernel_matches_ref(self, valid_dtype, seed):
         from repro.kernels.write_path.kernel import apply_trim
         from repro.kernels.write_path.ref import (
             apply_trim_flat,
@@ -337,7 +353,7 @@ class TestTrimPath:
 
         rng = np.random.default_rng(seed)
         k, b, lba_pages = 24, 8, 128
-        valid = rng.random((k, b)) < 0.5
+        valid = (rng.random((k, b)) < 0.5).astype(valid_dtype)
         page_map = rng.integers(-1, k * b, lba_pages).astype(np.int32)
         lba = int(rng.integers(0, lba_pages))
         if rng.random() < 0.3:
@@ -347,7 +363,9 @@ class TestTrimPath:
             jnp.asarray(page_map), jnp.asarray(valid),
             jnp.asarray(lba), jnp.asarray(old_pm),
         )
-        ref_pm, ref_v = apply_trim_ref(*args)
+        ref_pm, ref_v = apply_trim_ref(
+            args[0], jnp.asarray(valid.astype(bool)), *args[2:])
+        ref_v = np.asarray(ref_v).astype(valid_dtype)
         flat_pm, flat_v = apply_trim_flat(*args)
         ker_pm, ker_v = apply_trim(*args, interpret=True)
         for got, ref, name in (

@@ -31,7 +31,9 @@ from repro.core.fleet import DriveSpec
 from repro.kernels.gc_compact.kernel import compact_slots
 from repro.kernels.write_path.kernel import apply_trim, apply_write
 
-from _runner_hlo import captured_runner, per_write_pool_copies
+from _runner_hlo import (
+    captured_runner, per_write_pool_converts, per_write_pool_copies,
+)
 
 GEOM = CONFIG.geometry()
 K, B, LBA = GEOM.n_blocks, GEOM.pages_per_block, GEOM.lba_pages
@@ -137,3 +139,11 @@ def test_fleet_runner_updates_pools_in_place(runner_hlo):
     of a cond in one, copies a page_map-, slot_lba- or valid-shaped array
     at Table 2 (a slot_lba copy alone would move 4.2 MB per write)."""
     assert per_write_pool_copies(runner_hlo, GEOM) == []
+
+
+def test_fleet_runner_keeps_valid_in_one_dtype(runner_hlo):
+    """No loop body, nor anything it calls, converts the whole valid pool
+    between bool and int32: the scan carries it as the int32 the kernels
+    update in place, so at Table 2 no write or erase pays a 1-MB round
+    trip each way."""
+    assert per_write_pool_converts(runner_hlo, GEOM) == []

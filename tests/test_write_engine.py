@@ -63,6 +63,8 @@ def _assert_identical(a, b, label: str):
     np.testing.assert_array_equal(a.app, b.app, err_msg=f"{label}: app")
     np.testing.assert_array_equal(a.mig, b.mig, err_msg=f"{label}: mig")
     assert int(a.state["n_dropped"]) == 0, f"{label}: writes dropped"
+    # the scan carries valid as int32 0/1; both engines hand back bool
+    assert a.state["valid"].dtype == b.state["valid"].dtype == np.bool_
     for key, arr in a.state.items():
         np.testing.assert_array_equal(
             np.asarray(arr), np.asarray(b.state[key]),
@@ -160,6 +162,9 @@ class TestFleetEngine:
         np.testing.assert_array_equal(got.mig, oracle.mig)
         for i, s in enumerate(specs):
             assert int(got.state(i)["n_dropped"]) == 0
+            # the scan carries valid as int32 0/1; the fleet hands back bool
+            assert got.state(i)["valid"].dtype == np.bool_
+            assert oracle.state(i)["valid"].dtype == np.bool_
             for key, arr in got.state(i).items():
                 np.testing.assert_array_equal(
                     np.asarray(arr), np.asarray(oracle.state(i)[key]),
